@@ -211,9 +211,9 @@ def run(n: int = 1 << 19):
     # ---- sync vs prefetched (async) paging -------------------------------
     # The SAME request mix through two engines sharing one fixed-
     # geometry spec: host-driven sync paging (decode on the block-
-    # boundary critical path) vs device-resident async paging (jitted
-    # window scan + DMA-prefetched block decodes consumed one window
-    # later). Gated: the prefetched path may not be slower per decoded
+    # boundary critical path) vs device-resident async paging (greedy
+    # feedback on device over a window + DMA-prefetched block decodes
+    # consumed one window later). Gated: the prefetched path may not be slower per decoded
     # token, and the trace-derived overlap fraction (decode time hidden
     # behind model compute / total decode wait) must stay majority-
     # hidden.

@@ -83,7 +83,7 @@ def main():
             return (vals.reshape(n_dev, -1),
                     jax.lax.psum(jnp.int32(0), "data") + jnp.int32(ok),
                     jax.lax.psum(hist, "data"))
-        return jax.jit(shd.shard_map_compat(
+        return jax.jit(shd.shard_map(
             body, mesh=mesh, in_specs=(P("data"),),
             out_specs=(P("data"), P(), P())))
 

@@ -25,10 +25,10 @@ quantizes blocks on eviction: smaller, but lossy like any fp8 cache.)
 ``--kv-paging async`` (with ``--kv-cache qlc``) moves paging off the
 host: evicted blocks live in a device-resident arena, block decodes
 are DMA-prefetched one admission window ahead, and the decode loop
-runs as one jitted scan per window (two host-to-device transfers and
-one device-to-host per window, regardless of window length). Tokens
-stay identical to sync paging; the prefetch hit/stall counters print
-at the end.
+keeps each window's greedy feedback on device (two host-to-device
+transfers and one device-to-host per window, regardless of window
+length). Tokens stay identical to sync paging; the prefetch
+hit/stall counters print at the end.
 
 Run:  PYTHONPATH=src python examples/serve_lm.py --arch xlstm-125m
 """
@@ -87,7 +87,7 @@ def main():
     ap.add_argument("--kv-paging", default="sync",
                     choices=["sync", "async"],
                     help="'async' pages blocks through the device-"
-                         "resident arena: jitted window decode + DMA-"
+                         "resident arena: windowed device decode + DMA-"
                          "prefetched block decodes (requires "
                          "--kv-cache qlc)")
     args = ap.parse_args()
@@ -187,7 +187,7 @@ def main():
                   f"{dense / ps['peak_referenced_bytes']:.2f}x")
         if args.kv_paging == "async":
             pf = st["prefetch"]
-            print(f"async paging: {st['async']['windows']} jitted "
+            print(f"async paging: {st['async']['windows']} decode "
                   f"windows ({st['async']['d2h_per_window']:.0f} d2h "
                   f"per window), prefetch {pf['hits']}/{pf['scheduled']} "
                   f"hits ({pf['stalled']} stalled, "

@@ -24,7 +24,7 @@ from repro.configs import get_config, reduced
 from repro.core import CodecRegistry
 from repro.models import moe as moe_mod
 from repro.data import DataConfig, SyntheticDataset
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_device_mesh
 from repro.models import init_params
 from repro.parallel import sharding as shd
 from repro.training import (OptConfig, Trainer, TrainerConfig, TrainConfig,
@@ -80,7 +80,7 @@ def main():
     args = ap.parse_args()
 
     cfg = build_cfg(args.preset)
-    mesh = make_test_mesh(model=2 if len(jax.devices()) > 1 else 1)
+    mesh = make_device_mesh(model=2 if len(jax.devices()) > 1 else 1)
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"model: {cfg.name} params~{cfg.param_count()/1e6:.1f}M")
 
@@ -120,14 +120,6 @@ def main():
                       f"bits/sym, "
                       f"{dm * wire / geo['ng']:.0f} wire B/token "
                       f"per collective")
-
-        if (args.comm == "qlc" and moe_channels
-                and not hasattr(jax, "shard_map")):
-            print("note: this jax lacks jax.shard_map — compressed "
-                  "grad collectives can't wrap the shardmap_a2a MoE "
-                  "forward; running the baseline grad wire with the "
-                  "compressed MoE expert wire")
-            args.comm = "baseline"
 
         baseline = jax.jit(make_baseline_step(cfg, opt_cfg, train_cfg,
                                               moe_channels=moe_channels))

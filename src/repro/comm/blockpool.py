@@ -32,6 +32,7 @@ Pressure handling (graceful degradation, never OOM):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, Optional, Tuple
 
@@ -54,6 +55,20 @@ class ArenaStale(RuntimeError):
     and its result being consumed — the slot was freed (and possibly
     rewritten) in between. Consuming the result would hand out stale
     container words, so the arena refuses with this typed error."""
+
+
+@functools.cache
+def _slot_writer():
+    import jax
+    from jax import lax
+    # the old buffer is donated: a write never holds two arenas in HBM
+    return jax.jit(lambda buf, words, slot: lax.dynamic_update_slice(
+        buf, words.astype(buf.dtype)[None], (slot, 0)), donate_argnums=0)
+
+
+def _write_slot(buf, words, slot: int):
+    """``buf[slot, :len(words)] = words``, in place."""
+    return _slot_writer()(buf, words, slot)
 
 
 class BlockArena:
@@ -113,7 +128,7 @@ class BlockArena:
         if n > self.slot_words:
             raise ValueError(f"container of {n} words exceeds the "
                              f"{self.slot_words}-word arena slot")
-        self._buf = self._buf.at[slot, :n].set(words)
+        self._buf = _write_slot(self._buf, words, slot)
         self._used_words[slot] = n
         self.writes += 1
         return self._gen[slot]

@@ -22,12 +22,26 @@ from repro.core.schemes import QLCScheme
 from repro.quant import e4m3
 
 
-def histogram_of_quantized(x: jnp.ndarray) -> np.ndarray:
-    """float tensor -> counts[256] of its block-32 e4m3 symbols."""
+# Symbols quantized per call (a multiple of the block size). On a TPU
+# the [n/32, 32] block view pads to 128 lanes, 4x the f32 bytes, so a
+# whole gradient vector at once would not fit beside the model.
+_PIECE = 1 << 24
+
+
+def quantized_symbols(x: jnp.ndarray) -> np.ndarray:
+    """float tensor -> its block-32 e4m3 symbols, flat ``uint8`` on the
+    host (a trailing partial block is dropped), quantized a piece at a
+    time."""
     flat = jnp.asarray(x, jnp.float32).reshape(-1)
     n = (flat.shape[0] // e4m3.BLOCK) * e4m3.BLOCK
-    codes, _ = e4m3.quantize_block32(flat[:n])
-    return np.bincount(np.asarray(codes).reshape(-1),
+    return np.concatenate([np.zeros(0, np.uint8)] + [
+        np.asarray(e4m3.quantize_block32(flat[i:min(i + _PIECE, n)])[0])
+        .reshape(-1) for i in range(0, n, _PIECE)])
+
+
+def histogram_of_quantized(x: jnp.ndarray) -> np.ndarray:
+    """float tensor -> counts[256] of its block-32 e4m3 symbols."""
+    return np.bincount(quantized_symbols(x),
                        minlength=256).astype(np.float64)
 
 
@@ -60,10 +74,7 @@ def calibrate_for_tensor(x: jnp.ndarray, scheme: Optional[QLCScheme] = None,
     the other half of the answer — the planner supports one plan per
     tensor type.)
     """
-    flat = jnp.asarray(x, jnp.float32).reshape(-1)
-    n = (flat.shape[0] // e4m3.BLOCK) * e4m3.BLOCK
-    codes, _ = e4m3.quantize_block32(flat[:n])
-    codes_np = np.asarray(codes).reshape(-1)
+    codes_np = quantized_symbols(x)
     counts = np.maximum(
         np.bincount(codes_np, minlength=256).astype(np.float64), 1e-6)
     tables = adapt.calibrate_tables(counts, scheme=scheme,
@@ -172,15 +183,8 @@ def kv_symbol_stream(arrays, mode: str = "qlc") -> np.ndarray:
     coding on top is not).
     """
     if mode == "e4m3":
-        parts = []
-        for a in arrays:
-            flat = jnp.asarray(a, jnp.float32).reshape(-1)
-            n = (flat.shape[0] // e4m3.BLOCK) * e4m3.BLOCK
-            if n:
-                codes, _ = e4m3.quantize_block32(flat[:n])
-                parts.append(np.asarray(codes).reshape(-1))
-        return (np.concatenate(parts) if parts
-                else np.zeros(0, np.uint8))
+        return np.concatenate([np.zeros(0, np.uint8)]
+                              + [quantized_symbols(a) for a in arrays])
     return np.concatenate(
         [np.ascontiguousarray(np.asarray(a)).view(np.uint8).reshape(-1)
          for a in arrays]) if arrays else np.zeros(0, np.uint8)

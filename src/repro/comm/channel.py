@@ -653,7 +653,7 @@ def measure_wire_Bps(mesh, axis: str, payload_bytes: int = 1 << 22, *,
     n = max(1, int(payload_bytes) // 4)
     perm = [(j, (j + 1) % d) for j in range(d)]
     spec = PartitionSpec(axis)
-    hop = jax.jit(shd.shard_map_compat(
+    hop = jax.jit(shd.shard_map(
         lambda a: jax.lax.ppermute(a, axis, perm),
         mesh=mesh, in_specs=spec, out_specs=spec))
     x = jax.device_put(jnp.zeros((d, n), jnp.float32),

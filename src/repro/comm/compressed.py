@@ -43,8 +43,8 @@ the escape pool needs them; the fusion saves the separate quantize and
 encode dispatches and their re-reads. Callers without an escape pool —
 the weight wire, serving, checkpoints — get the full
 symbols-stay-in-VMEM benefit.) Note: ``pallas_call`` has no shard_map
-replication rule, so callers must pass ``check_rep=False`` to
-``shard_map`` when enabling kernels.
+replication rule, so kernels run inside ``repro.parallel.sharding.
+shard_map``, which turns replication checking off.
 """
 from __future__ import annotations
 
@@ -172,11 +172,28 @@ def _decode(words: jnp.ndarray, tables: CodecTables, cfg: CommConfig):
     return codec.decode_chunks(words, tables, cfg.chunk_symbols)
 
 
+def words_of_bytes(b: jnp.ndarray) -> jnp.ndarray:
+    """u8 [..., 4m] -> u32 [..., m], little-endian (``ndarray.view``).
+
+    Shifts of strided slices rather than a bitcast of a ``[..., 4]``
+    view: on a TPU that view's minor dimension of 4 pads to 128 lanes
+    (32x the bytes) wherever XLA materializes it.
+    """
+    b = b.astype(jnp.uint32)
+    return (b[..., 0::4] | (b[..., 1::4] << 8) | (b[..., 2::4] << 16)
+            | (b[..., 3::4] << 24))
+
+
+def bytes_of_words(w: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of :func:`words_of_bytes`: u32 [..., m] -> u8 [..., 4m]."""
+    shift = (jnp.arange(4 * w.shape[-1], dtype=jnp.uint32) % 4) * 8
+    return ((jnp.repeat(w, 4, axis=-1) >> shift) & 0xFF).astype(jnp.uint8)
+
+
 def _raw_payload(chunks: jnp.ndarray) -> WirePayload:
-    """Raw e4m3 wire: bitcast u8 -> u32, no escapes."""
+    """Raw e4m3 wire: u8 -> u32 words, no escapes."""
     *lead, n_chunks, k = chunks.shape
-    raw = jax.lax.bitcast_convert_type(
-        chunks.reshape(*lead, n_chunks, k // 4, 4), jnp.uint32)
+    raw = words_of_bytes(chunks)
     return WirePayload(
         words=raw,
         flags=jnp.zeros((*lead, n_chunks), dtype=jnp.uint8),
@@ -240,8 +257,7 @@ def _assemble_payload(chunks: jnp.ndarray, words: jnp.ndarray,
     escape = nbits > jnp.uint32(cfg.capacity_words * 32)
     pool_slots = cfg.pool_slots(n_chunks)
 
-    raw = jax.lax.bitcast_convert_type(
-        chunks.reshape(*lead, n_chunks, k // 4, 4), jnp.uint32)
+    raw = words_of_bytes(chunks)
 
     # Escaped chunks scatter their raw form into the pool; non-escaped
     # and pool-overflowing chunks are dropped.
@@ -296,8 +312,7 @@ def _gather_pool_raw(payload: WirePayload, cfg: CommConfig) -> jnp.ndarray:
     esc_idx, _ = _escape_slots(payload.flags, pool_slots)
     raw_words = _gather_pool_rows(
         payload.pool, jnp.minimum(esc_idx, pool_slots - 1))
-    raw = jax.lax.bitcast_convert_type(raw_words, jnp.uint8)  # [...,K/4,4]
-    return raw.reshape(*lead, n_chunks, k)
+    return bytes_of_words(raw_words).reshape(*lead, n_chunks, k)
 
 
 def decompress_codes(payload: WirePayload, tables,
@@ -326,8 +341,8 @@ def _decompress_codes(payload: WirePayload, tables: Optional[CodecTables],
     *lead, n_chunks, _ = payload.words.shape
 
     if not cfg.enabled:
-        chunks = jax.lax.bitcast_convert_type(payload.words, jnp.uint8)
-        codes_out = chunks.reshape(*lead, n_chunks * k)
+        codes_out = bytes_of_words(payload.words).reshape(
+            *lead, n_chunks * k)
         ok = jnp.ones(tuple(lead), dtype=bool) if lead else jnp.bool_(True)
         return codes_out, ok
 
@@ -447,7 +462,7 @@ def _pool_values(payload: WirePayload, scales: jnp.ndarray,
     chunk_scales = scales.astype(jnp.float32).reshape(*lead, n_chunks, k32)
     pool_scales = _scatter_pool_rows(chunk_scales, slot, pool_slots)
 
-    pool_u8 = jax.lax.bitcast_convert_type(payload.pool, jnp.uint8)
+    pool_u8 = bytes_of_words(payload.pool)
     pool_vals = e4m3.dequantize_block32(
         pool_u8.reshape(*lead, pool_slots * k),
         pool_scales.reshape(*lead, pool_slots * k32),

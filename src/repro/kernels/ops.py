@@ -3,7 +3,9 @@
 Handles padding to tile multiples, table marshaling, tile-size
 autotuning, and backend dispatch: on TPU the compiled kernels run
 natively; elsewhere they run in interpret mode (bit-exact semantics)
-so the whole framework is runnable and testable on CPU.
+so the whole framework is runnable and testable on CPU. That choice is
+made here only (``_interpret_default``): the kernel functions take
+``interpret`` as a required argument.
 
 Entry points
 ------------
@@ -59,9 +61,10 @@ def _interpret_default() -> bool:
 
 # tile_chunks per chunk-size bucket, from a VMEM working-set model
 # (~20 B/symbol of per-chunk intermediates; target ≈512 KiB per program
-# to leave headroom for double buffering). Measured interpret-mode and
-# v5e numbers agree that more, smaller chunks per tile wins for short
-# chunks while K=4096 must drop to 2 to stay under budget.
+# to leave headroom for double buffering): short chunks take more per
+# tile, K=4096 drops to 2. ``tests/test_tpu_compile.py`` checks that the
+# K=256 and K=1024 tiles compile within a v5e's VMEM; no tile size has
+# been timed on a chip.
 _TILE_CHUNKS_TABLE = {
     64: 32,
     128: 32,
@@ -122,8 +125,7 @@ def _sid_rows(scheme_ids, n_chunks: int, n_schemes: int,
     else:
         sid = jnp.asarray(scheme_ids, jnp.int32).reshape(-1)
         assert sid.shape[0] == n_chunks, (sid.shape, n_chunks)
-    # Out-of-range slots clamp at the gather (jnp.take clips); callers
-    # are expected to pass slots < n_schemes.
+    # Callers pass slots < n_schemes; the kernels do not check.
     del n_schemes
     return _pad_rows(sid[:, None], tile_chunks)
 
@@ -241,7 +243,8 @@ def quantize_encode(x: jnp.ndarray, tables: CodecTables,
       tables: codec tables.
       capacity_words: slot size per chunk in 32-bit words.
       emit_codes: also return the raw e4m3 symbols (escape-pool callers).
-      emit_hist: also return the 256-bin symbol histogram.
+      emit_hist: also return the 256-bin symbol histogram (counted from
+        the kernel's symbol output).
 
     Returns:
       (words u32 [n, CW], nbits u32 [n], scales f32 [n, K/32]
@@ -253,26 +256,22 @@ def quantize_encode(x: jnp.ndarray, tables: CodecTables,
     if tile_chunks is None:
         tile_chunks = auto_tile_chunks(k, n_chunks)
     padded = _pad_rows(x, tile_chunks)
-    n_pad_rows = padded.shape[0] - n_chunks
     outs = qlc_fused.fused_encode_pallas(
         padded,
         jnp.asarray(tables.enc_code, dtype=jnp.uint32),
         jnp.asarray(tables.enc_len, dtype=jnp.uint32),
         capacity_words=capacity_words,
         tile_chunks=tile_chunks,
-        emit_codes=emit_codes,
-        emit_hist=emit_hist,
+        emit_codes=emit_codes or emit_hist,
         interpret=interpret,
     )
     words, nbits, scales = outs[:3]
     result = [words[:n_chunks], nbits[:n_chunks, 0], scales[:n_chunks]]
-    idx = 3
     if emit_codes:
-        result.append(outs[idx][:n_chunks])
-        idx += 1
+        result.append(outs[3][:n_chunks])
     if emit_hist:
-        # Padded rows are all-zero chunks => quantize to symbol 0.
-        result.append(outs[idx].at[0].add(-n_pad_rows * k))
+        result.append(jnp.bincount(outs[3][:n_chunks].reshape(-1),
+                                   length=256).astype(jnp.int32))
     return tuple(result)
 
 
@@ -336,15 +335,13 @@ def decode_dequantize_accumulate(acc: jnp.ndarray, words: jnp.ndarray,
       tables / scheme_ids: as in :func:`decode_dequantize`.
 
     Returns:
-      [n_chunks, K] f32 ``acc + dequantize(decode(words))``. With a
-      zero ``acc`` this is bit-exact against ``decode_dequantize``;
-      with a live accumulator it matches a separate decode-then-add to
-      one f32 ulp — the compiler may FMA-contract the in-kernel
-      dequantize multiply into the add (excess precision), which no
-      graph-level fence reliably prevents. Transport-level bit-identity
-      therefore comes from running the SAME accumulate op sequence on
-      every path (``transport._accumulate_row_pieces``), never from mixing
-      this fused form with decode-then-add.
+      [n_chunks, K] f32 ``acc + dequantize(decode(words))``, bit-exact
+      against ``decode_dequantize`` followed by a separate add: the
+      kernel rounds the dequantize product through its output tile
+      before adding, so no FMA contraction can keep excess precision.
+      Transport-level bit-identity still comes from running the SAME
+      accumulate op sequence on every path
+      (``transport._accumulate_row_pieces``).
     """
     if interpret is None:
         interpret = _interpret_default()

@@ -1,12 +1,21 @@
 """Pallas TPU kernel: chunk-parallel QLC encode.
 
-Per chunk: gather (code, len) from the 256-entry encoder LUT, exclusive
-prefix-sum of lengths, then each <=11-bit code touches at most two
-consecutive 32-bit words of the slot -> two scatter-adds (disjoint bit
-ranges make add equivalent to or).
+One chunk per sublane row. Per 128-symbol block the kernel looks up
+each symbol's packed ``code | len << 16`` in the 256-entry encoder LUT
+(vectorized), then walks the block's symbols in order, appending each
+code to a 32-bit bit buffer. A full buffer is emitted as the next slot
+word by a select into one of ``ceil(CW/128)`` lane-block registers —
+the bit-serial form of the reference's exclusive prefix sum plus two
+scatter-adds, with no scatter, lane ``cumsum`` or 1-D gather, none of
+which Mosaic lowers.
 
-VMEM per program (TILE_CHUNKS=8, K=1024, CW=384):
-  symbols 8 KiB, words 12 KiB, codes+lens+offsets 3*32 KiB ~= 116 KiB.
+Overflowing chunks keep the reference's slot contents exactly: the
+reference clamps every word index to ``capacity_words - 1`` and adds,
+so the last slot word is the wrapping sum of every "natural" word at or
+past it. ``nbits`` is the exact encoded length either way.
+
+VMEM per program (TILE_CHUNKS=8, K=1024, CW=353): symbols 8 KiB,
+words 12 KiB, table 1 KiB.
 """
 from __future__ import annotations
 
@@ -16,38 +25,81 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.qlc_decode import (LANES, block_loop, gather,
+                                      lane_blocks, load_blocks, pad_lanes,
+                                      table_row)
+
 DEFAULT_TILE_CHUNKS = 8
 
 
-def _encode_kernel(sym_ref, enc_code_ref, enc_len_ref, words_ref, nbits_ref,
-                   *, capacity_words: int):
-    sym = sym_ref[...].astype(jnp.int32)            # (TC, K)
-    tc, k = sym.shape
-    enc_code = enc_code_ref[...]                    # (256,) u32
-    enc_len = enc_len_ref[...]                      # (256,) u32
+def code_table(enc_code: jnp.ndarray, enc_len: jnp.ndarray) -> jnp.ndarray:
+    """Packed ``code | len << 16`` encoder LUT as a ``(1, 256)`` row."""
+    return table_row(enc_code.astype(jnp.int32)
+                     | (enc_len.astype(jnp.int32) << 16), jnp.int32)
 
-    codes = jnp.take(enc_code, sym)                 # (TC, K) u32
-    lens = jnp.take(enc_len, sym)                   # (TC, K) u32
 
-    nbits = jnp.sum(lens, axis=1, dtype=jnp.uint32)         # (TC,)
-    offsets = jnp.cumsum(lens, axis=1, dtype=jnp.uint32) - lens
+def pack_rows(sym_block, code_ref, words_ref, nbits_ref, *,
+              chunk_symbols: int):
+    """Bit-pack ``(TC, K)`` symbols into the ``(TC, CW)`` words tile.
 
-    word_idx = (offsets >> 5).astype(jnp.int32)
-    shift = offsets & jnp.uint32(31)
-    lo = codes << shift                              # u32 shift wraps
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   codes >> (jnp.uint32(32) - shift))
+    ``sym_block(start, width)`` returns the ``(TC, 128)`` int32 symbols
+    of one lane block (lanes past ``width`` ignored).
+    """
+    tc, cw = words_ref.shape
+    ctab = load_blocks(code_ref, tc)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tc, LANES), 1)
+    last = cw - 1
 
-    word_idx = jnp.minimum(word_idx, capacity_words - 1)
-    hi_idx = jnp.minimum(word_idx + 1, capacity_words - 1)
+    def put(word, at, out, tail, emit):
+        """Where ``emit``, store ``word`` as natural slot word ``at``."""
+        keep = emit & (at < last)
+        out = tuple(
+            jnp.where(keep & ((at >> 7) == b) & (lane == (at & 127)),
+                      word, blk)
+            for b, blk in enumerate(out))
+        return out, tail + jnp.where(emit & (at >= last), word,
+                                     jnp.uint32(0))
 
-    words = jnp.zeros((tc, capacity_words), dtype=jnp.uint32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tc, k), 0)
-    words = words.at[rows, word_idx].add(lo, mode="drop")
-    words = words.at[rows, hi_idx].add(hi, mode="drop")
+    def step(i, state, packed):
+        fill, buf, at, out, tail = state
+        p = jnp.take_along_axis(packed, jnp.full((tc, LANES), i, jnp.int32),
+                                axis=1)
+        code = (p & 0xFFFF).astype(jnp.uint32)
+        word = buf | (code << fill)
+        spill = jnp.where(fill == 0, jnp.uint32(0),
+                          code >> (jnp.uint32(32) - fill))
+        fill = fill + (p >> 16).astype(jnp.uint32)
+        full = fill >= 32
+        out, tail = put(word, at, out, tail, full)
+        return (jnp.where(full, fill - 32, fill), jnp.where(full, spill, word),
+                at + full.astype(jnp.int32), out, tail)
 
-    words_ref[...] = words
-    nbits_ref[...] = nbits[:, None]
+    def block(start, width, state):
+        packed = gather(ctab, sym_block(start, width))
+        return jax.lax.fori_loop(
+            0, width, lambda i, s: step(i, s, packed), state)
+
+    zero_u = jnp.zeros((tc, LANES), jnp.uint32)
+    n_out = len(lane_blocks(cw))
+    state = (zero_u, zero_u, jnp.zeros((tc, LANES), jnp.int32),
+             (zero_u,) * n_out, zero_u)
+    fill, buf, at, out, tail = block_loop(chunk_symbols, block, state)
+    out, tail = put(buf, at, out, tail, at >= 0)      # the partial word
+    lw = jnp.full((tc, LANES), last, jnp.int32)
+    out = tuple(jnp.where(((lw >> 7) == b) & (lane == (last & 127)), tail, o)
+                for b, o in enumerate(out))
+    for (s, w), blk in zip(lane_blocks(cw), out):
+        words_ref[:, s:s + w] = blk[:, :w]
+    nbits_ref[...] = ((at.astype(jnp.uint32) << 5) + fill)[:, :1]
+
+
+def _encode_kernel(sym_ref, code_ref, words_ref, nbits_ref, *,
+                   chunk_symbols: int):
+    def sym_block(start, width):
+        return pad_lanes(sym_ref[:, pl.ds(start, width)].astype(jnp.int32))
+
+    pack_rows(sym_block, code_ref, words_ref, nbits_ref,
+              chunk_symbols=chunk_symbols)
 
 
 @functools.partial(
@@ -55,22 +107,18 @@ def _encode_kernel(sym_ref, enc_code_ref, enc_len_ref, words_ref, nbits_ref,
     static_argnames=("capacity_words", "tile_chunks", "interpret"))
 def encode_pallas(symbols: jnp.ndarray, enc_code: jnp.ndarray,
                   enc_len: jnp.ndarray, *, capacity_words: int,
-                  tile_chunks: int = DEFAULT_TILE_CHUNKS,
-                  interpret: bool = True):
+                  tile_chunks: int, interpret: bool):
     """Encode [n_chunks, K] u8 -> ([n_chunks, CW] u32, [n_chunks, 1] u32)."""
     n_chunks, k = symbols.shape
     assert n_chunks % tile_chunks == 0, (n_chunks, tile_chunks)
-    grid = (n_chunks // tile_chunks,)
-
-    kernel = functools.partial(_encode_kernel, capacity_words=capacity_words)
-
+    ctab = code_table(enc_code, enc_len)
+    kernel = functools.partial(_encode_kernel, chunk_symbols=k)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_chunks // tile_chunks,),
         in_specs=[
             pl.BlockSpec((tile_chunks, k), lambda i: (i, 0)),
-            pl.BlockSpec((enc_code.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((enc_len.shape[0],), lambda i: (0,)),
+            pl.BlockSpec(ctab.shape, lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((tile_chunks, capacity_words), lambda i: (i, 0)),
@@ -81,4 +129,4 @@ def encode_pallas(symbols: jnp.ndarray, enc_code: jnp.ndarray,
             jax.ShapeDtypeStruct((n_chunks, 1), jnp.uint32),
         ],
         interpret=interpret,
-    )(symbols, enc_code, enc_len)
+    )(symbols, ctab)

@@ -1,46 +1,38 @@
 """Fused Pallas TPU kernels: quantize→encode and decode→dequantize.
 
-Design
-------
-The unfused pipeline runs four dispatches with HBM round-trips between
-them::
+The unfused pipeline runs separate dispatches with HBM round-trips
+between them::
 
     f32 --quantize--> u8 codes --(HBM)--> encode --> words
-                               `--(HBM)--> histogram
 
 The fused encode kernel performs block-32 e4m3 quantization AND the QLC
-bit-pack in one ``pallas_call``: the uint8 symbol tile never leaves
-VMEM. Per tile of ``TILE_CHUNKS`` chunks it
+bit-pack in one ``pallas_call``: the symbol tile never leaves VMEM. Per
+tile of ``TILE_CHUNKS`` chunks it
 
   1. computes block-32 amax scales (``scale = amax / 480``, the paper's
-     §3 block scaling) and quantizes ``x / scale`` to eXmY e4m3 with a
-     branch-free bit-trick encoder (exponent extraction + one
-     round-to-nearest-even per element — bit-exact against the
-     table-search oracle in ``repro.quant.e4m3``, which tests enforce);
-  2. gathers (code, len) from the 256-entry encoder LUT, takes an
-     exclusive prefix sum of lengths, and scatter-adds each ≤11-bit
-     code into at most two consecutive 32-bit words of the chunk slot;
-  3. optionally accumulates the 256-bin symbol histogram as a side
-     output (revolving output block; used for on-line recalibration) and
-     optionally emits the raw symbols (needed only when the caller
-     maintains an escape pool, e.g. the compressed collectives).
+     §3 block scaling) with a 5-step lane butterfly inside each 128-lane
+     block, and quantizes ``x / scale`` to eXmY e4m3 with a branch-free
+     bit-trick encoder (exponent extraction + one round-to-nearest-even
+     per element — bit-exact against the table-search oracle in
+     ``repro.quant.e4m3``, which tests enforce), into a VMEM scratch;
+  2. bit-packs the scratch with the encoder of ``qlc_encode``;
+  3. optionally emits the raw symbols (needed when the caller keeps an
+     escape pool, e.g. the compressed collectives; ``ops`` derives the
+     symbol histogram from them).
 
-The mirror decode kernel reads packed words, walks the chunk with the
-paper's O(1) per-symbol step (3-bit area code → length, no tree walk),
-and multiplies each decoded symbol's table value by its block scale
-in-register, producing float output directly — decoded symbols also
-never touch HBM. Its LUT operands are stacked per scheme with a
-per-chunk scheme slot, so one dispatch decodes chunks encoded under
-different schemes (paper §7 multi-LUT; see ``qlc_decode`` for the
-operand layout).
+The mirror decode kernel runs the ``qlc_decode`` bit-window loop and
+looks each symbol's index up in the stacked ``[S * 256]`` e4m3 *value*
+table (the decoder LUT composed with the value table outside the
+kernel), multiplying by the block scale in-register — decoded symbols
+never touch HBM. Its optional accumulate form adds a running sum in the
+same pass (the ring reduce-scatter's per-hop inner loop).
 
-VMEM per program (TILE_CHUNKS=8, K=1024, CW=384):
-  x f32 32 KiB, words 12 KiB, codes+lens+offsets 3*32 KiB, scales
-  1 KiB, LUTs ~4 KiB  ≈ 145 KiB — far under the ~16 MiB/core budget.
+VMEM per program (TILE_CHUNKS=8, K=1024, CW=353): x f32 32 KiB, symbol
+scratch 32 KiB, words 12 KiB, scales 1 KiB, tables ≈2 KiB.
 
 ``ops.quantize_encode`` / ``ops.decode_dequantize`` are the public
-entry points (padding, table marshaling, tile autotuning, CPU interpret
-fallback).
+entry points (padding, table marshaling, tile autotuning, interpret
+mode off-TPU).
 """
 from __future__ import annotations
 
@@ -52,6 +44,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.qlc_decode import (LANES, area_table, block_loop,
+                                      decode_rows, gather, lane_blocks,
+                                      load_blocks, pad_lanes, table_row)
+from repro.kernels.qlc_encode import code_table, pack_rows
 from repro.quant.e4m3 import BLOCK, E4M3_MAX_FINITE
 
 DEFAULT_TILE_CHUNKS = 8
@@ -88,103 +84,83 @@ def _e4m3_bits_encode(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(jnp.signbit(x), code | 0x80, code)
 
 
-def _quantize_tile(x: jnp.ndarray):
-    """(TC, K) f32 -> (symbols i32 (TC, K), scales f32 (TC, K/BLOCK)).
+def _quantize_block(x: jnp.ndarray):
+    """(TC, 128) f32 -> (symbols i32, lane-broadcast block scales f32).
 
-    Identical arithmetic to ``e4m3.quantize_block32`` (amax over blocks
-    of 32, ``scale = amax/480`` or 1 for zero blocks, one f32 divide),
-    so the fused path is bit-exact against the unfused oracle.
+    Identical arithmetic to ``e4m3.quantize_block32`` (amax over aligned
+    blocks of 32 lanes, ``scale = amax/480`` or 1 for zero blocks, one
+    f32 divide), so the fused path is bit-exact against the unfused
+    oracle. The amax is a lane butterfly: partners ``lane ^ s`` for
+    s < 32 never leave their 32-lane block.
     """
-    tc, k = x.shape
-    xb = x.reshape(tc, k // BLOCK, BLOCK)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    amax = jnp.abs(x)
+    for s in (1, 2, 4, 8, 16):
+        amax = jnp.maximum(amax, jnp.take_along_axis(amax, lane ^ s, axis=1))
     # Same explicit reciprocal multiply as quantize_block32 (see the
     # comment there) — required for bit-exact fused/unfused parity.
     inv = np.float32(1.0) / np.float32(E4M3_MAX_FINITE)
     scale = jnp.where(amax > 0, amax * inv, 1.0)
-    xs = (xb / scale).reshape(tc, k)
-    return _e4m3_bits_encode(xs), scale[..., 0]
+    return _e4m3_bits_encode(x / scale), scale
 
 
 # --------------------------------------------------------------------------
 # Fused quantize -> encode
 # --------------------------------------------------------------------------
 
-def _pack_codes(sym, enc_code, enc_len, capacity_words):
-    """QLC bit-pack of a (TC, K) symbol tile (same math as qlc_encode)."""
-    tc, k = sym.shape
-    codes = jnp.take(enc_code, sym)                 # (TC, K) u32
-    lens = jnp.take(enc_len, sym)                   # (TC, K) u32
+def _fused_encode_kernel(x_ref, code_ref, words_ref, nbits_ref, scales_ref,
+                         *rest, emit_codes: bool):
+    codes_ref = rest[0] if emit_codes else None
+    sym_ref = rest[-1]                                # (TC, K) i32 scratch
+    tc, k = x_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tc, LANES), 1)
+    n_sregs = len(lane_blocks(k // BLOCK))
 
-    nbits = jnp.sum(lens, axis=1, dtype=jnp.uint32)
-    offsets = jnp.cumsum(lens, axis=1, dtype=jnp.uint32) - lens
+    def quantize(start, width, sregs):
+        x = pad_lanes(x_ref[:, pl.ds(start, width)].astype(jnp.float32))
+        sym, scale = _quantize_block(x)
+        sym_ref[:, pl.ds(start, width)] = sym[:, :width]
+        if emit_codes:
+            codes_ref[:, pl.ds(start, width)] = (
+                sym[:, :width].astype(jnp.uint8))
+        # Lane 32q holds block q's scale. Its place in the compact
+        # (TC, K/32) row is start/32 + q = 4j + q: register j >> 5, lane
+        # 4 (j & 31) + q, whose lane index is ≡ q (mod 4).
+        j = start // LANES
+        g = jnp.take_along_axis(scale, (lane & 3) * BLOCK, axis=1)
+        mine = ((lane >> 2) == (j & 31)) & ((lane & 3) < width // BLOCK)
+        return tuple(jnp.where(mine & ((j >> 5) == r), g, s)
+                     for r, s in enumerate(sregs))
 
-    word_idx = (offsets >> 5).astype(jnp.int32)
-    shift = offsets & jnp.uint32(31)
-    lo = codes << shift                             # u32 shift wraps
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   codes >> (jnp.uint32(32) - shift))
+    sregs = block_loop(
+        k, quantize,
+        tuple(jnp.zeros((tc, LANES), jnp.float32) for _ in range(n_sregs)))
+    for (s, w), reg in zip(lane_blocks(k // BLOCK), sregs):
+        scales_ref[:, s:s + w] = reg[:, :w]
 
-    word_idx = jnp.minimum(word_idx, capacity_words - 1)
-    hi_idx = jnp.minimum(word_idx + 1, capacity_words - 1)
-
-    words = jnp.zeros((tc, capacity_words), dtype=jnp.uint32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tc, k), 0)
-    words = words.at[rows, word_idx].add(lo, mode="drop")
-    words = words.at[rows, hi_idx].add(hi, mode="drop")
-    return words, nbits
-
-
-def _fused_encode_kernel(x_ref, enc_code_ref, enc_len_ref, *out_refs,
-                         capacity_words: int, emit_codes: bool,
-                         emit_hist: bool):
-    words_ref, nbits_ref, scales_ref = out_refs[:3]
-    rest = list(out_refs[3:])
-    codes_ref = rest.pop(0) if emit_codes else None
-    hist_ref = rest.pop(0) if emit_hist else None
-
-    x = x_ref[...].astype(jnp.float32)
-    sym, scale = _quantize_tile(x)
-    scales_ref[...] = scale
-    if emit_codes:
-        codes_ref[...] = sym.astype(jnp.uint8)
-    if emit_hist:
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            hist_ref[...] = jnp.zeros_like(hist_ref)
-        bins = jax.lax.broadcasted_iota(jnp.int32, (256,), 0)
-        onehot = (sym.reshape(-1)[:, None] == bins[None, :])
-        hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=0)
-
-    words, nbits = _pack_codes(sym, enc_code_ref[...], enc_len_ref[...],
-                               capacity_words)
-    words_ref[...] = words
-    nbits_ref[...] = nbits[:, None]
+    pack_rows(lambda start, width: pad_lanes(
+        sym_ref[:, pl.ds(start, width)]), code_ref, words_ref, nbits_ref,
+        chunk_symbols=k)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("capacity_words", "tile_chunks", "emit_codes",
-                     "emit_hist", "interpret"))
+                     "interpret"))
 def fused_encode_pallas(x: jnp.ndarray, enc_code: jnp.ndarray,
                         enc_len: jnp.ndarray, *, capacity_words: int,
-                        tile_chunks: int = DEFAULT_TILE_CHUNKS,
-                        emit_codes: bool = False, emit_hist: bool = False,
-                        interpret: bool = True):
+                        tile_chunks: int, emit_codes: bool,
+                        interpret: bool):
     """Quantize+encode [n_chunks, K] float -> packed QLC slots.
 
     Returns ``(words [n, CW] u32, nbits [n, 1] u32, scales [n, K/32]
-    f32, *extras)`` where extras are ``codes [n, K] u8`` (if
-    ``emit_codes``) then ``hist [256] i32`` (if ``emit_hist``).
+    f32[, codes [n, K] u8])`` — codes only with ``emit_codes``.
     """
     n_chunks, k = x.shape
     assert n_chunks % tile_chunks == 0, (n_chunks, tile_chunks)
     assert k % BLOCK == 0, k
-    grid = (n_chunks // tile_chunks,)
-
-    kernel = functools.partial(
-        _fused_encode_kernel, capacity_words=capacity_words,
-        emit_codes=emit_codes, emit_hist=emit_hist)
+    ctab = code_table(enc_code, enc_len)
+    kernel = functools.partial(_fused_encode_kernel, emit_codes=emit_codes)
 
     out_specs = [
         pl.BlockSpec((tile_chunks, capacity_words), lambda i: (i, 0)),
@@ -199,92 +175,50 @@ def fused_encode_pallas(x: jnp.ndarray, enc_code: jnp.ndarray,
     if emit_codes:
         out_specs.append(pl.BlockSpec((tile_chunks, k), lambda i: (i, 0)))
         out_shape.append(jax.ShapeDtypeStruct((n_chunks, k), jnp.uint8))
-    if emit_hist:
-        # Every grid step maps to the same block => accumulate in place.
-        out_specs.append(pl.BlockSpec((256,), lambda i: (0,)))
-        out_shape.append(jax.ShapeDtypeStruct((256,), jnp.int32))
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_chunks // tile_chunks,),
         in_specs=[
             pl.BlockSpec((tile_chunks, k), lambda i: (i, 0)),
-            pl.BlockSpec((enc_code.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((enc_len.shape[0],), lambda i: (0,)),
+            pl.BlockSpec(ctab.shape, lambda i: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((tile_chunks, k), jnp.int32)],
         interpret=interpret,
-    )(x, enc_code, enc_len)
+    )(x, ctab)
 
 
 # --------------------------------------------------------------------------
 # Fused decode -> dequantize
 # --------------------------------------------------------------------------
 
-def _fused_decode_kernel(words_ref, scales_ref, sid_ref, dec_lut_ref,
-                         area_sb_ref, area_starts_ref, value_tab_ref,
-                         *rest_refs, chunk_symbols: int,
-                         prefix_bits: int, out_dtype, accumulate: bool):
-    if accumulate:
-        acc_ref, out_ref, sym_ref = rest_refs
-    else:
-        out_ref, sym_ref = rest_refs
-        acc_ref = None
-    words = words_ref[...]                       # (TC, CW) uint32
-    tc, cw = words.shape
-    n_area = area_sb_ref.shape[-1]
-    # Stacked per-scheme LUTs (S, 256)/(S, A), flattened: each chunk's
-    # sid offsets every LUT gather, so one dispatch decodes a tile whose
-    # chunks were encoded under different schemes (§7 multi-LUT).
-    dec = dec_lut_ref[...].astype(jnp.uint32).reshape(-1)
-    sb_t = area_sb_ref[...].astype(jnp.uint32).reshape(-1)
-    st_t = area_starts_ref[...].astype(jnp.uint32).reshape(-1)
-    sid = sid_ref[...][:, 0].astype(jnp.int32)   # (TC,) scheme slot
-    vtab = value_tab_ref[...]                    # (256,) f32 e4m3 values
-    pmask = jnp.uint32((1 << prefix_bits) - 1)
-    pbits = jnp.uint32(prefix_bits)
+def _fused_decode_kernel(words_ref, scales_ref, sid_ref, area_ref, val_ref,
+                         *rest, chunk_symbols: int, prefix_bits: int,
+                         n_area: int, accumulate: bool):
+    acc_ref, out_ref = rest if accumulate else (None, rest[0])
+    tc = words_ref.shape[0]
+    vals = load_blocks(val_ref, tc)
+    scales = load_blocks(scales_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tc, LANES), 1)
 
-    # The sequential loop carries only the bit cursor; symbols land in
-    # a VMEM scratch via per-column stores (the same idiom as the
-    # standalone decode kernel — cheaper than threading a (TC, K)
-    # array through the loop carry). The dequantize (value-table
-    # gather * block scale) then runs ONCE, fully vectorized, and the
-    # float tile is written in one store.
-    def body(i, bitpos):
-        widx = (bitpos >> 5).astype(jnp.int32)               # (TC,)
-        shift = bitpos & jnp.uint32(31)
-        w0 = jnp.take_along_axis(words, widx[:, None], axis=1)[:, 0]
-        w1 = jnp.take_along_axis(
-            words, jnp.minimum(widx + 1, cw - 1)[:, None], axis=1)[:, 0]
-        window = (w0 >> shift) | jnp.where(
-            shift == 0, jnp.uint32(0), w1 << (jnp.uint32(32) - shift))
-        area = (window & pmask).astype(jnp.int32)
-        sb = jnp.take(sb_t, sid * n_area + area)
-        payload = (window >> pbits) & ((jnp.uint32(1) << sb) - jnp.uint32(1))
-        rank = jnp.take(st_t, sid * n_area + area) + payload
-        sym = jnp.take(
-            dec,
-            sid * 256 + jnp.minimum(rank, jnp.uint32(255)).astype(jnp.int32))
-        sym_ref[:, pl.dslice(i, 1)] = sym.astype(jnp.int32)[:, None]
-        return bitpos + pbits + sb
+    def emit(start, width, idx):
+        scale = gather(scales, start // BLOCK + (lane >> 5))
+        flat = (gather(vals, idx) * scale)[:, :width]
+        if accumulate:
+            # The running sum of the ring reduce-scatter's per-hop
+            # accumulate. The product goes through the output tile
+            # first, so it rounds to f32 before the add (no FMA) and the
+            # sum is bit-equal to decode-then-add.
+            out_ref[:, pl.ds(start, width)] = flat
+            flat = acc_ref[:, pl.ds(start, width)] + out_ref[
+                :, pl.ds(start, width)]
+        out_ref[:, pl.ds(start, width)] = flat.astype(out_ref.dtype)
 
-    bitpos0 = jnp.zeros((tc,), dtype=jnp.uint32)
-    jax.lax.fori_loop(0, chunk_symbols, body, bitpos0)
-
-    vals = jnp.take(vtab, sym_ref[...])          # (TC, K) f32
-    vb = vals.reshape(tc, chunk_symbols // BLOCK, BLOCK)
-    vb = vb * scales_ref[...][..., None]
-    flat = vb.reshape(tc, chunk_symbols)
-    if accumulate:
-        # In-register running sum: the ring reduce-scatter's per-hop
-        # accumulate never materializes the hop's decoded values in HBM.
-        # The barrier stops the compiler from contracting the dequant
-        # multiply and this add into one FMA — the product must round
-        # to f32 first, or the fused form drifts a ulp from the
-        # decode-then-add paths it is tested bit-equal against.
-        flat = acc_ref[...] + jax.lax.optimization_barrier(flat)
-    out_ref[...] = flat.astype(out_dtype)
+    decode_rows(words_ref, sid_ref, area_ref, emit,
+                chunk_symbols=chunk_symbols, prefix_bits=prefix_bits,
+                n_area=n_area)
 
 
 @functools.partial(
@@ -295,10 +229,9 @@ def fused_decode_pallas(words: jnp.ndarray, scales: jnp.ndarray,
                         scheme_ids: jnp.ndarray, dec_lut: jnp.ndarray,
                         area_sb: jnp.ndarray, area_starts: jnp.ndarray,
                         value_tab: jnp.ndarray, acc: jnp.ndarray = None,
-                        *, chunk_symbols: int, prefix_bits: int = 3,
-                        tile_chunks: int = DEFAULT_TILE_CHUNKS,
-                        out_dtype=jnp.float32,
-                        interpret: bool = True) -> jnp.ndarray:
+                        *, chunk_symbols: int, prefix_bits: int,
+                        tile_chunks: int, out_dtype=jnp.float32,
+                        interpret: bool) -> jnp.ndarray:
     """Decode+dequantize [n_chunks, CW] u32 slots -> [n_chunks, K] float.
 
     ``scales`` is [n_chunks, K/32] f32 (block-32 scales, chunk-major).
@@ -324,26 +257,23 @@ def fused_decode_pallas(words: jnp.ndarray, scales: jnp.ndarray,
         assert jnp.dtype(out_dtype) == jnp.dtype(jnp.float32), (
             "accumulate form is f32-only", out_dtype)
         assert acc.shape == (n_chunks, chunk_symbols), acc.shape
-    s, a = area_sb.shape
-    grid = (n_chunks // tile_chunks,)
+    atab = area_table(area_sb, area_starts)
+    vtab = table_row(jnp.take(value_tab, dec_lut.astype(jnp.int32)),
+                     jnp.float32)
 
     kernel = functools.partial(
         _fused_decode_kernel, chunk_symbols=chunk_symbols,
-        prefix_bits=prefix_bits, out_dtype=out_dtype,
+        prefix_bits=prefix_bits, n_area=area_sb.shape[1],
         accumulate=accumulate)
-
     in_specs = [
         pl.BlockSpec((tile_chunks, cw), lambda i: (i, 0)),
         pl.BlockSpec((tile_chunks, chunk_symbols // BLOCK),
                      lambda i: (i, 0)),
         pl.BlockSpec((tile_chunks, 1), lambda i: (i, 0)),
-        pl.BlockSpec((s, dec_lut.shape[1]), lambda i: (0, 0)),
-        pl.BlockSpec((s, a), lambda i: (0, 0)),
-        pl.BlockSpec((s, a), lambda i: (0, 0)),
-        pl.BlockSpec((value_tab.shape[0],), lambda i: (0,)),
+        pl.BlockSpec(atab.shape, lambda i: (0, 0)),
+        pl.BlockSpec(vtab.shape, lambda i: (0, 0)),
     ]
-    operands = [words, scales, scheme_ids, dec_lut, area_sb, area_starts,
-                value_tab]
+    operands = [words, scales, scheme_ids, atab, vtab]
     if accumulate:
         in_specs.append(pl.BlockSpec((tile_chunks, chunk_symbols),
                                      lambda i: (i, 0)))
@@ -351,13 +281,11 @@ def fused_decode_pallas(words: jnp.ndarray, scales: jnp.ndarray,
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_chunks // tile_chunks,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile_chunks, chunk_symbols),
                                lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_chunks, chunk_symbols),
                                        out_dtype),
-        scratch_shapes=[pltpu.VMEM((tile_chunks, chunk_symbols),
-                                   jnp.int32)],
         interpret=interpret,
     )(*operands)

@@ -14,13 +14,12 @@ Pipeline (grid step i over word tiles)::
       decode            [t0 ========][t1 ========][t2 ========]
                          ^ wait sem(0)            ^ slots alternate
 
-Step i waits on slot ``i % 2``, starts the DMA for tile i+1 into slot
-``(i+1) % 2`` *before* decoding tile i, then runs the same bit-window
-area-code decode as ``qlc_decode._decode_kernel`` (stacked multi-LUT
-operands, per-chunk scheme slots). The words operand therefore stays
-in ``ANY`` (HBM) memory space — Pallas never auto-copies it — and only
-2 * tile_chunks * capacity_words * 4 bytes of it are VMEM-resident at
-a time, independent of container size.
+Step i starts the DMA for tile i+1 into slot ``(i+1) % 2``, waits on
+slot ``i % 2``, then runs ``qlc_decode.decode_kernel`` on that slot
+(stacked multi-LUT operands, per-chunk scheme slots). The words operand
+therefore stays in ``pl.ANY`` (HBM) memory space — Pallas never
+auto-copies it — and only 2 * tile_chunks * capacity_words * 4 bytes of
+it are VMEM-resident at a time, independent of container size.
 
 On CPU the kernel runs in interpret mode where the DMAs are synchronous
 copies: bit-exact semantics, no overlap. Overlap is *measured* (not
@@ -37,18 +36,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.qlc_decode import (LANES, area_table, decode_kernel,
+                                      table_row)
 
-def _prefetch_decode_kernel(words_hbm_ref, sid_ref, dec_lut_ref,
-                            area_sb_ref, area_starts_ref, out_ref,
-                            vmem_ref, dma_sems, *, chunk_symbols: int,
-                            prefix_bits: int, n_tiles: int):
+
+def _prefetch_decode_kernel(words_hbm_ref, sid_ref, area_ref, dec_ref,
+                            out_ref, vmem_ref, dma_sems, *,
+                            chunk_symbols: int, prefix_bits: int,
+                            n_area: int, n_tiles: int):
     i = pl.program_id(0)
     slot = jax.lax.rem(i, 2)
 
+    tc = vmem_ref.shape[1]
+
     def tile_copy(tile, into_slot):
         return pltpu.make_async_copy(
-            words_hbm_ref.at[tile], vmem_ref.at[into_slot],
-            dma_sems.at[into_slot])
+            words_hbm_ref.at[pl.ds(pl.multiple_of(tile * tc, tc), tc)],
+            vmem_ref.at[into_slot], dma_sems.at[into_slot])
 
     # Warm-up: the first step issues its own DMA (no lookbehind exists).
     @pl.when(i == 0)
@@ -62,37 +66,9 @@ def _prefetch_decode_kernel(words_hbm_ref, sid_ref, dec_lut_ref,
         tile_copy(i + 1, jax.lax.rem(i + 1, 2)).start()
 
     tile_copy(i, slot).wait()
-    words = vmem_ref[pl.dslice(slot, 1)][0]          # (TC, CW) uint32
-
-    tc, cw = words.shape
-    n_area = area_sb_ref.shape[-1]
-    dec = dec_lut_ref[...].astype(jnp.uint32).reshape(-1)
-    sb_t = area_sb_ref[...].astype(jnp.uint32).reshape(-1)
-    st_t = area_starts_ref[...].astype(jnp.uint32).reshape(-1)
-    sid = sid_ref[...][:, 0].astype(jnp.int32)       # (TC,) scheme slot
-    pmask = jnp.uint32((1 << prefix_bits) - 1)
-    pbits = jnp.uint32(prefix_bits)
-
-    def body(k, bitpos):
-        widx = (bitpos >> 5).astype(jnp.int32)
-        shift = bitpos & jnp.uint32(31)
-        w0 = jnp.take_along_axis(words, widx[:, None], axis=1)[:, 0]
-        w1 = jnp.take_along_axis(
-            words, jnp.minimum(widx + 1, cw - 1)[:, None], axis=1)[:, 0]
-        window = (w0 >> shift) | jnp.where(
-            shift == 0, jnp.uint32(0), w1 << (jnp.uint32(32) - shift))
-        area = (window & pmask).astype(jnp.int32)
-        sb = jnp.take(sb_t, sid * n_area + area)
-        payload = (window >> pbits) & ((jnp.uint32(1) << sb) - jnp.uint32(1))
-        rank = jnp.take(st_t, sid * n_area + area) + payload
-        sym = jnp.take(
-            dec,
-            sid * 256 + jnp.minimum(rank, jnp.uint32(255)).astype(jnp.int32))
-        out_ref[:, pl.dslice(k, 1)] = sym.astype(jnp.uint8)[:, None]
-        return bitpos + pbits + sb
-
-    bitpos0 = jnp.zeros((tc,), dtype=jnp.uint32)
-    jax.lax.fori_loop(0, chunk_symbols, body, bitpos0)
+    decode_kernel(vmem_ref.at[slot], sid_ref, area_ref, dec_ref, out_ref,
+                   chunk_symbols=chunk_symbols, prefix_bits=prefix_bits,
+                   n_area=n_area)
 
 
 @functools.partial(
@@ -102,9 +78,9 @@ def _prefetch_decode_kernel(words_hbm_ref, sid_ref, dec_lut_ref,
 def prefetch_decode_pallas(words: jnp.ndarray, scheme_ids: jnp.ndarray,
                            dec_lut: jnp.ndarray, area_sb: jnp.ndarray,
                            area_starts: jnp.ndarray,
-                           *, chunk_symbols: int, prefix_bits: int = 3,
-                           tile_chunks: int = 8,
-                           interpret: bool = True) -> jnp.ndarray:
+                           *, chunk_symbols: int, prefix_bits: int,
+                           tile_chunks: int,
+                           interpret: bool) -> jnp.ndarray:
     """Decode [n_chunks, capacity_words] u32 slots -> [n_chunks, K] u8
     with the words streamed HBM -> VMEM through a double-buffered DMA.
 
@@ -116,24 +92,25 @@ def prefetch_decode_pallas(words: jnp.ndarray, scheme_ids: jnp.ndarray,
     assert n_chunks % tile_chunks == 0, (n_chunks, tile_chunks)
     assert dec_lut.ndim == 2 and area_sb.ndim == 2, (
         "stacked LUT operands required: dec_lut [S, 256], area_* [S, A]")
-    s, a = area_sb.shape
     n_tiles = n_chunks // tile_chunks
-    tiled = words.reshape(n_tiles, tile_chunks, cw)
+    # A DMA moves whole 128-lane tiles: pad the slot width to one.
+    cw = -(-cw // LANES) * LANES
+    words = jnp.pad(words, ((0, 0), (0, cw - words.shape[1])))
+    atab = area_table(area_sb, area_starts)
+    dtab = table_row(dec_lut, jnp.int32)
 
     kernel = functools.partial(
         _prefetch_decode_kernel, chunk_symbols=chunk_symbols,
-        prefix_bits=prefix_bits, n_tiles=n_tiles)
-
-    out = pl.pallas_call(
+        prefix_bits=prefix_bits, n_area=area_sb.shape[1], n_tiles=n_tiles)
+    return pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=[
             # Words stay in HBM; the kernel DMAs tiles itself.
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((tile_chunks, 1), lambda i: (i, 0)),
-            pl.BlockSpec((s, dec_lut.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((s, a), lambda i: (0, 0)),
-            pl.BlockSpec((s, a), lambda i: (0, 0)),
+            pl.BlockSpec(atab.shape, lambda i: (0, 0)),
+            pl.BlockSpec(dtab.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_chunks, chunk_symbols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_chunks, chunk_symbols), jnp.uint8),
@@ -142,5 +119,4 @@ def prefetch_decode_pallas(words: jnp.ndarray, scheme_ids: jnp.ndarray,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(tiled, scheme_ids, dec_lut, area_sb, area_starts)
-    return out
+    )(words, scheme_ids, atab, dtab)

@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST precede every other import (jax locks the
-# device count at first init). 512 placeholder CPU devices back the
-# production meshes: 16x16 single pod, 2x16x16 multi-pod.
+# The lines above MUST precede every other import (jax locks the
+# platform and device count at first init). 512 placeholder CPU devices
+# back the production meshes: 16x16 single pod, 2x16x16 multi-pod. The
+# CPU platform is pinned for this process and, through the environment,
+# for every --sweep child, so a dry run never takes a host's TPU.
 
 import argparse          # noqa: E402
 import gzip              # noqa: E402

@@ -1,8 +1,10 @@
-"""Production mesh definitions.
+"""Mesh definitions.
 
-A function (never a module-level constant) so importing this module
-never touches jax device state. Single pod = 256 chips as (16 data,
-16 model); multi-pod adds a leading "pod" axis (2 pods = 512 chips).
+Functions (never module-level constants) so importing this module
+never touches jax device state. ``make_device_mesh`` shapes the devices
+present, which is what the launchers run on. ``make_production_mesh``
+describes the 256-chip pod (16 data, 16 model; multi-pod adds a
+leading "pod" axis) for the compile-only dry run.
 The "pod" axis is the DCN tier: the hierarchical transport
 (``ChannelSpec(pod_axis="pod")``) rings over "data" within a pod and
 bridges pods with one compressed exchange per hop group.
@@ -27,8 +29,9 @@ def make_production_mesh(*, multi_pod: bool = False, pods: int = None):
     return jax.make_mesh(shape, axes)
 
 
-def make_test_mesh(*, devices=None, model: int = 2, pods: int = 1):
-    """Small mesh over whatever devices exist (tests/examples).
+def make_device_mesh(*, devices=None, model: int = 2, pods: int = 1):
+    """A (data, model) mesh over the devices present — one chip, a
+    four-chip host, or fake CPU devices in tests. The launchers' mesh.
 
     ``pods > 1`` simulates a multi-host topology on fake devices
     (``XLA_FLAGS=--xla_force_host_platform_device_count=N``): the

@@ -1,4 +1,9 @@
-"""Production serving launcher: continuous-batching request engine.
+"""Serving launcher: continuous-batching request engine.
+
+Runs on the devices present (``launch.mesh.make_device_mesh``): one
+v5e chip serves phi3-mini-3.8b at its published widths, all 32 layers,
+with bf16 weights created directly in bf16 (≈7.6 GB of the chip's
+16 GB), the rest of HBM left to the KV cache.
 
 Requests go through ``repro.serving.Engine`` (PR 6): submit
 ``GenerationRequest``s, drive ``step()``, ``poll()`` the tokens. The
@@ -20,30 +25,81 @@ the first prefill, blocks encoded to QLC containers on eviction,
 decoded from the (prefix-deduped) pooled bytes on access — losslessly,
 so tokens match the dense run. ``--kv-block`` sets the block size.
 
-Example:
+Examples:
+  python -m repro.launch.serve --arch phi3-mini-3.8b
   python -m repro.launch.serve --arch musicgen-medium --reduced \\
       --batch 8 --new-tokens 32 --wire qlc --kv-cache qlc
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced as make_reduced
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.mesh import make_device_mesh
 from repro.models import init_params
 from repro.parallel import sharding as shd
+from repro.runtime import enable_compile_cache
 from repro.serving import BlockPool, Engine, GenerationRequest, KVCacheSpec
+
+
+def serving_config(arch: str, *, reduced: bool = False):
+    """``arch``'s config as served: its own widths and depth (or the
+    reduced smoke preset), weights in the model's published dtype."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = dataclasses.replace(make_reduced(cfg), frontend=None,
+                                  frontend_prefix_len=0)
+    return dataclasses.replace(cfg, param_dtype=cfg.dtype)
+
+
+def init_serving_params(cfg, seed: int = 0):
+    """Random weights made on the device in ``cfg.param_dtype`` by one
+    jitted init, so no wider copy of them is ever resident."""
+    return jax.jit(init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed))
+
+
+def kv_cache_spec(mode: str, block_tokens: int, paging: str):
+    """The paged-cache spec the launcher serves ``--kv-cache mode`` with
+    (None for the dense cache)."""
+    if mode == "none":
+        return None
+    # async needs compile-time container offsets
+    return KVCacheSpec(block_tokens=block_tokens, mode=mode,
+                       exact_capacity=paging != "async")
+
+
+def serve_requests(params, cfg, prompts, *, batch: int, new_tokens: int,
+                   kv_spec=None, kv_paging: str = "sync", mesh=None):
+    """Submit one request per prompt row, run the engine to completion.
+
+    Returns ``(outs, stats, seconds)``; ``outs[i].tokens`` are prompt
+    ``i``'s generated tokens.
+    """
+    eng = Engine(params, cfg, max_seq_len=prompts.shape[1] + new_tokens + 8,
+                 max_batch=batch, kv_spec=kv_spec,
+                 pool=BlockPool(1 << 30) if kv_spec is not None else None,
+                 kv_paging=kv_paging, mesh=mesh)
+    t0 = time.time()
+    handles = [eng.submit(GenerationRequest(
+        prompt=p, max_new_tokens=new_tokens)) for p in prompts]
+    eng.run()
+    dt = time.time() - t0
+    outs = [eng.poll(h) for h in handles]
+    assert all(s.state == "finished" for s in outs), \
+        [(s.request_id, s.state, s.error) for s in outs]
+    return outs, eng.stats(), dt
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--batch", type=int, default=4,
                     help="engine slots (max concurrent sequences)")
     ap.add_argument("--requests", type=int, default=None,
@@ -63,26 +119,18 @@ def main():
                     choices=["sync", "async"],
                     help="'async' keeps evicted blocks in a device-"
                          "resident arena and decodes them via DMA "
-                         "prefetch under a jitted window scan "
+                         "prefetch behind an on-device decode window "
                          "(requires --kv-cache qlc)")
     args = ap.parse_args()
     if args.kv_paging == "async" and args.kv_cache != "qlc":
         ap.error("--kv-paging async requires --kv-cache qlc")
     n_req = args.requests or args.batch + 2
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        import dataclasses
-        cfg = make_reduced(cfg)
-        cfg = dataclasses.replace(cfg, frontend=None,
-                                  frontend_prefix_len=0)
-        mesh = make_test_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-
+    enable_compile_cache()
+    cfg = serving_config(args.arch, reduced=args.reduced)
+    mesh = make_device_mesh()
     with shd.use_mesh(mesh):
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        max_seq_len = args.prompt_len + args.new_tokens + 8
+        params = init_serving_params(cfg)
         if args.wire == "qlc":
             from repro.comm.calibrate import histogram_of_tree
             from repro.core import CodecRegistry
@@ -97,40 +145,23 @@ def main():
             params = jax.jit(
                 lambda w: open_params(w, wc, channel=ch))(wired)
 
-        kv_spec = pool = None
-        if args.kv_cache != "none":
-            kv_spec = KVCacheSpec(
-                block_tokens=args.kv_block, mode=args.kv_cache,
-                # async needs compile-time container offsets
-                exact_capacity=args.kv_paging != "async")
-            pool = BlockPool(1 << 30)
-        eng = Engine(params, cfg, max_seq_len=max_seq_len,
-                     max_batch=args.batch, kv_spec=kv_spec, pool=pool,
-                     kv_paging=args.kv_paging,
-                     mesh=mesh if not args.reduced else None)
-
         prompts = np.asarray(jax.random.randint(
             jax.random.PRNGKey(1), (n_req, args.prompt_len), 0,
             cfg.vocab_size))
-        t0 = time.time()
-        handles = [eng.submit(GenerationRequest(
-            prompt=p, max_new_tokens=args.new_tokens)) for p in prompts]
-        eng.run()
-        dt = time.time() - t0
-        outs = [eng.poll(h) for h in handles]
-        assert all(s.state == "finished" for s in outs), \
-            [(s.request_id, s.state, s.error) for s in outs]
+        outs, st, dt = serve_requests(
+            params, cfg, prompts, batch=args.batch,
+            new_tokens=args.new_tokens,
+            kv_spec=kv_cache_spec(args.kv_cache, args.kv_block,
+                                  args.kv_paging),
+            kv_paging=args.kv_paging,
+            mesh=mesh if not args.reduced else None)
 
-        st = eng.stats()
         if args.kv_cache == "qlc":
             # the lossless contract: pooled compressed paging is
             # token-identical to a dense single-request run
-            solo = Engine(params, cfg, max_seq_len=max_seq_len,
-                          max_batch=1)
-            h = solo.submit(GenerationRequest(
-                prompt=prompts[0], max_new_tokens=args.new_tokens))
-            solo.run()
-            assert np.array_equal(outs[0].tokens, solo.poll(h).tokens), \
+            solo, _, _ = serve_requests(params, cfg, prompts[:1], batch=1,
+                                        new_tokens=args.new_tokens)
+            assert np.array_equal(outs[0].tokens, solo[0].tokens), \
                 "qlc KV cache must be token-identical"
             ps = st["pool"]
             print(f"kv-cache=qlc: peak {ps['peak_referenced_bytes']} "

@@ -1,15 +1,14 @@
-"""Production training launcher.
+"""Training launcher.
 
-On a real TPU cluster this is the per-host entry point (jax distributed
-init -> production mesh -> trainer). On CPU it runs reduced configs for
-verification. The dry-run (``repro.launch.dryrun``) is the compile-only
-counterpart for the full-size cells.
+The per-host entry point: (jax distributed init ->) a mesh over the
+devices present (``launch.mesh.make_device_mesh``) -> trainer. On CPU
+it runs reduced configs for verification. The dry-run
+(``repro.launch.dryrun``) is the compile-only counterpart for the
+full-size cells.
 
 Examples:
   python -m repro.launch.train --arch deepseek-moe-16b --reduced \\
       --steps 50 --comm qlc
-  python -m repro.launch.train --arch nemotron-4-340b --multi-pod \\
-      --steps 100000   # real cluster
 """
 from __future__ import annotations
 
@@ -26,9 +25,10 @@ from repro.comm.channel import Channel, ChannelSpec
 from repro.configs import get_config, reduced as make_reduced
 from repro.core import CodecRegistry
 from repro.data import DataConfig, SyntheticDataset
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.mesh import make_device_mesh
 from repro.models import init_params
 from repro.parallel import sharding as shd
+from repro.runtime import enable_compile_cache
 from repro.training import (OptConfig, Trainer, TrainerConfig, TrainConfig,
                             init_compressed_opt_state, make_baseline_step,
                             make_compressed_step)
@@ -40,7 +40,6 @@ def main():
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
                     help="small same-family config (CPU verification)")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--pods", type=int, default=1,
                     help="leading 'pod' (DCN-tier) mesh axis size. With "
                          "--pods N > 1 the compressed gradient wire "
@@ -110,14 +109,11 @@ def main():
         raise SystemExit(
             "--transport hierarchical needs --pods > 1 (a pod axis to "
             "bridge); with one pod it would just be the ring")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
-        mesh = make_test_mesh(pods=args.pods)
-    else:
-        mesh = make_production_mesh(
-            multi_pod=args.multi_pod,
-            pods=args.pods if args.pods > 1 else None)
+    mesh = make_device_mesh(pods=args.pods)
     if args.moe_wire == "qlc" and cfg.moe is not None:
         # an explicit compressed expert wire implies real expert-
         # parallel dispatch (the other impls never touch the wire)

@@ -561,7 +561,7 @@ def _moe_shardmap_a2a(params, x: jnp.ndarray,
     tok_spec = jax.sharding.PartitionSpec(token_axes)
     rep = jax.sharding.PartitionSpec()
     exp = jax.sharding.PartitionSpec("model")
-    out = shd.shard_map_compat(
+    out = shd.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, rep, exp, exp, exp),
         out_specs=tok_spec,

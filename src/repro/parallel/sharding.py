@@ -89,10 +89,8 @@ class ShardingRules:
         dims = list(shape) if shape is not None else [None] * len(
             logical_axes)
         # Axes that are Manual in the current trace (inside shard_map)
-        # or explicitly blocked (inside a spmd_axis_name'd vmap) cannot
-        # appear in sharding constraints — treat them as taken.
+        # cannot appear in sharding constraints — treat them as taken.
         used: set = set(_manual_axes())
-        used.update(getattr(_STATE, "blocked", frozenset()))
         parts = [self._resolve(name, d, mesh, param, used)
                  for name, d in zip(logical_axes, dims)]
         return P(*parts)
@@ -111,23 +109,6 @@ def get_rules() -> ShardingRules:
 
 
 @contextlib.contextmanager
-def block_axes(axes):
-    """Trace-time guard: keep ``axes`` out of emitted sharding specs.
-
-    Needed around function bodies traced under ``jax.vmap(...,
-    spmd_axis_name=axes)`` on older jax, where the vmapped axes are
-    invisible to both the abstract mesh and the named-axis env but are
-    still illegal in with_sharding_constraint specs.
-    """
-    old = getattr(_STATE, "blocked", frozenset())
-    _STATE.blocked = frozenset(old) | frozenset(axes)
-    try:
-        yield
-    finally:
-        _STATE.blocked = old
-
-
-@contextlib.contextmanager
 def use_mesh(mesh: Mesh):
     """Enter a mesh context (framework-tracked + jax ``with mesh:``)."""
     old = getattr(_STATE, "mesh", None)
@@ -140,12 +121,8 @@ def use_mesh(mesh: Mesh):
 
 
 def _abstract_mesh():
-    """jax.sharding.get_abstract_mesh, absent on older jax (<0.5)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
     try:
-        return fn()
+        return jax.sharding.get_abstract_mesh()
     except Exception:
         return None
 
@@ -164,49 +141,23 @@ def _manual_axes() -> frozenset:
     """Mesh axes currently under manual (shard_map) control."""
     am = _abstract_mesh()
     if am is None or not am.axis_names:
-        # Older jax (<0.5) has no abstract mesh; fall back to the named
-        # axis env. It cannot distinguish manual from auto axes, so be
-        # conservative and treat every in-scope named axis as manual —
-        # constraints lose at most a GSPMD layout hint, never
-        # correctness.
-        try:
-            from jax._src import core as _jcore
-            return frozenset(_jcore.get_axis_env().axis_sizes)
-        except Exception:
-            return frozenset()
-    try:
-        return frozenset(
-            n for n, t in zip(am.axis_names, am.axis_types)
-            if "Manual" in str(t))
-    except Exception:
         return frozenset()
+    return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes=None):
-    """shard_map across jax versions (no replication checking).
+def shard_map(f, *, mesh, in_specs, out_specs, manual_axes=None):
+    """``jax.shard_map`` without replication checking.
 
-    New jax exposes ``jax.shard_map(axis_names=..., check_vma=...)``;
-    older releases have ``jax.experimental.shard_map.shard_map`` with
-    the complementary ``auto=`` set and ``check_rep=``. Replication
-    checking must stay off either way: the compressed collectives can
+    Replication checking must stay off: the compressed collectives can
     run Pallas kernels, which have no replication rule.
-
-    ``manual_axes=None`` means fully manual over every mesh axis — the
-    only mode that works on BOTH jax lines (on older jax the partially
-    -auto form trips the XLA SPMD partitioner; see the train step's
-    stage-1 fallback).
+    ``manual_axes=None`` means fully manual over every mesh axis.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": False}
+    kw = {"check_vma": False}
     if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def logical_constraint(x: jax.Array, logical_axes: Sequence[Optional[str]]

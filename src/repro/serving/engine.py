@@ -242,12 +242,22 @@ def generate_from_wire(wired_params, wire_codec, cfg: ModelConfig,
 
 @functools.lru_cache(maxsize=8)
 def _paged_step(cfg: ModelConfig):
-    """Jitted one-token decode step, cached per config — the engine and
-    repeated ``generate_paged`` calls (dense baseline + paged run)
-    reuse one compiled executable instead of re-tracing a fresh
-    lambda."""
-    return jax.jit(lambda p, tok, st, pos: decode_step(p, cfg, tok, st,
-                                                       pos))
+    """Jitted one-token greedy decode step, cached per config — the
+    engine (sync and async paging alike) and the legacy paged loop run
+    this one compiled executable.
+
+    ``(params, tok [B,1], states, pos [B,1]) -> (next tok [B,1] int32,
+    pos + 1, states)``: the greedy argmax (first index of the max, as
+    ``np.argmax``) runs on device, so the step's output can feed the
+    next step without a host round trip. The states are donated: each
+    step updates the cache in place, so steps queued ahead on the
+    device hold no extra copies."""
+    def step(p, tok, st, pos):
+        lg, st = decode_step(p, cfg, tok, st, pos)
+        nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)[:, None]
+        return nxt, pos + 1, st
+
+    return jax.jit(step, donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=8)
@@ -269,34 +279,28 @@ def _prefill_from_fn(cfg: ModelConfig):
         p, cfg, tokens, st, start_pos=start))
 
 
-@functools.lru_cache(maxsize=32)
-def _window_step(cfg: ModelConfig, window: int):
-    """Jitted greedy multi-token decode: ONE ``lax.scan`` over
-    ``window`` tokens — the async engine's admission-window step.
+def _decode_window(cfg: ModelConfig, params, tok, pos, states,
+                   window: int):
+    """Greedy decode of ``window`` tokens — the async engine's
+    admission-window step.
 
-    The greedy argmax feeds back *inside* the scan, so dispatching a
-    window costs one host->device transfer (the seed token + positions)
-    and one device->host transfer (the window's tokens), independent of
-    ``window`` — the zero-per-token-host-transfer contract the
-    transfer-count probe in the tests pins down.
+    Each token is one dispatch of :func:`_paged_step`, the sync path's
+    own executable, and its output token feeds the next step on device,
+    so a window costs one host->device transfer (the seed token +
+    positions) and one device->host transfer (the window's tokens),
+    independent of ``window`` — the zero-per-token-host-transfer
+    contract the transfer-count probe in the tests pins down. One
+    ``lax.scan`` over the window would dispatch once, but on a TPU its
+    loop body compiles to different roundings than the step, and the
+    tokens would drift from the sync path's.
 
     Returns ``(generated tokens [B, window], states)``.
     """
-
-    def run(params, tok0, pos0, states):
-        def body(carry, _):
-            tok, st, pos = carry
-            lg, st = decode_step(params, cfg, tok, st, pos)
-            nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)[:, None]
-            return (nxt, st, pos + 1), nxt[:, 0]
-
-        (_, states, _), gen = jax.lax.scan(
-            body, (tok0, states, pos0), None, length=window)
-        # gen row t = the token generated by step t (greedy argmax);
-        # the carry already re-fed it, so the host only reads results.
-        return jnp.moveaxis(gen, 0, 1), states
-
-    return jax.jit(run)
+    step, gen = _paged_step(cfg), []
+    for _ in range(window):
+        tok, pos, states = step(params, tok, states, pos)
+        gen.append(tok)
+    return jnp.concatenate(gen, axis=1), states
 
 
 def generate_paged(params, cfg: ModelConfig, prompts: jnp.ndarray,
@@ -342,8 +346,7 @@ def _paged_loop(params, cfg: ModelConfig, prompts: jnp.ndarray,
     toks = [tok]
     for t in range(serve_cfg.max_new_tokens - 1):
         pos = jnp.full((b, 1), s + t, jnp.int32)
-        lg, states = step(params, tok, states, pos)
+        tok, _, states = step(params, tok, states, pos)
         states = kv_cache.note_tokens(states, s + t + 1)
-        tok = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)[:, None]
         toks.append(tok)
     return jnp.concatenate(toks, axis=1)

@@ -56,8 +56,8 @@ from repro.comm.blockpool import (ArenaExhausted, BlockArena, BlockPool,
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import init_decode_states, ssm
-from repro.serving.engine import (_paged_step, _prefill_fn,
-                                  _prefill_from_fn, _window_step)
+from repro.serving.engine import (_decode_window, _paged_step,
+                                  _prefill_fn, _prefill_from_fn)
 from repro.serving.kv_cache import (KVCacheSpec, PagedKVCache,
                                     SSMBoundaryTracker, calibrate_cache)
 
@@ -147,8 +147,9 @@ class Engine:
     exact_capacity=False)``) moves paging device-resident: evicted
     block containers live in a :class:`~repro.comm.blockpool.BlockArena`
     of ``arena_slots`` slots, block decodes are DMA-prefetched at
-    window boundaries, and decode runs as one jitted scan per
-    admission window (constant host transfers per window). Token
+    window boundaries, and decode runs a whole admission window with
+    the greedy feedback on device (constant host transfers per
+    window). Token
     output is identical to ``"sync"``; both paging modes share one
     pool (device-framed containers are byte-identical to host ones).
     """
@@ -263,15 +264,15 @@ class Engine:
                 tokens[b, 0] = seq.toks[-1]
                 pos[b, 0] = seq.prompt_len + len(seq.toks) - 1
             t0 = time.perf_counter()
-            lg, self._states = self._step_fn(
+            nxt, _, self._states = self._step_fn(
                 self.params, jnp.asarray(tokens), self._states,
                 jnp.asarray(pos))
-            lg_np = np.asarray(lg)          # forces the dispatch
+            nxt = np.asarray(nxt)           # forces the dispatch
             self._decode_s += time.perf_counter() - t0
             self._decode_tokens += len(active)
             for b, rid in active:
                 seq = self._seqs[rid]
-                seq.toks.append(int(np.argmax(lg_np[b, 0])))
+                seq.toks.append(int(nxt[b, 0]))
                 self._note_boundary(seq)
                 try:
                     self._page(seq)
@@ -284,9 +285,9 @@ class Engine:
                    if s.state in ("waiting", "running"))
 
     def _step_async(self) -> int:
-        """One *admission window* of decode steps as a single jitted
-        scan (``engine._window_step``): the host uploads one seed token
-        + position per slot, the greedy feedback stays on device, and
+        """One *admission window* of decode steps
+        (``engine._decode_window``): the host uploads one seed token +
+        position per slot, the greedy feedback stays on device, and
         one array of generated tokens comes back — host transfers per
         window are constant (2 up, 1 down), independent of the window
         length. The window ends exactly at the nearest block boundary
@@ -324,12 +325,12 @@ class Engine:
             tok_dev = jnp.asarray(tokens)
             pos_dev = jnp.asarray(pos)
             self._window_h2d += 2
-            wf = _window_step(self.cfg, window)
             with jax.transfer_guard("disallow"):
-                # The probe: any per-token host callback inside the
-                # scan would raise here.
-                gen_dev, self._states = wf(self.params, tok_dev,
-                                           pos_dev, self._states)
+                # The probe: any per-token host transfer inside the
+                # window would raise here.
+                gen_dev, self._states = _decode_window(
+                    self.cfg, self.params, tok_dev, pos_dev,
+                    self._states, window)
             gen = np.asarray(gen_dev)       # ONE d2h for the window
             self._window_d2h += 1
             self._windows += 1
@@ -544,7 +545,16 @@ class Engine:
 
     def _ensure_arena(self, slot_words: int) -> BlockArena:
         if self._codec.arena is None:
-            arena = BlockArena(self._arena_slots, slot_words)
+            # The arena never holds more bytes than the pool may
+            # reference: a slot frames a whole group-stacked block (all
+            # layers of a phi3-mini block are ~40 MB), so 256 of them
+            # would outgrow HBM. Blocks past the last slot decode
+            # straight from their own device words.
+            n_slots = self._arena_slots
+            if self.pool is not None:
+                n_slots = min(n_slots, max(
+                    1, self.pool.capacity_bytes // (4 * slot_words)))
+            arena = BlockArena(n_slots, slot_words)
             self._codec.arena = arena
             if self.pool is not None and self.pool.arena is None:
                 self.pool.arena = arena

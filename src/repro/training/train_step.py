@@ -35,7 +35,7 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm import CommConfig
 from repro.comm.channel import Channel, ChannelSpec
@@ -132,10 +132,6 @@ def step_channels(codec, comm_cfg: CommConfig = None, *,
     rs_ch = {ax: open_axis(rs_codec, rs_cfg, rs_t, ax) for ax in rs_order}
     ag_ch = {ax: open_axis(ag_codec, ag_cfg, ag_t, ax) for ax in rs_order}
     return rs_ch, ag_ch, rs_cfg
-
-
-# Version-compat shard_map now lives with the other mesh helpers.
-_shard_map = shd.shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,9 +263,11 @@ def _replication_factor(pspec: P, mesh: Mesh,
 
 def flat_geometry(model_cfg: ModelConfig, mesh: Mesh,
                   train_cfg: TrainConfig, comm_cfg: CommConfig):
-    """(n_local, n_padded, seg, weight_vec) of the per-model-rank flat
-    parameter vector. ``weight_vec`` downweights model-replicated leaves
-    so the psum'd grad norm is exact."""
+    """(n_local, n_padded, seg, (leaf_starts, leaf_weights)) of the
+    per-model-rank flat parameter vector. Position ``i`` of the flat
+    vector has weight ``leaf_weights[j]`` for the last ``leaf_starts[j]
+    <= i``: model-replicated leaves are downweighted so the psum'd grad
+    norm is exact, and the padding tail weighs 0."""
     pspecs, shapes = _manual_param_specs(model_cfg, mesh)
     dp_total = dp_size_of(mesh, train_cfg)
     k = comm_cfg.chunk_symbols
@@ -284,10 +282,9 @@ def flat_geometry(model_cfg: ModelConfig, mesh: Mesh,
     n_local = int(sum(sizes))
     n_padded = -(-n_local // (dp_total * k)) * (dp_total * k)
     seg = n_padded // dp_total
-    w = np.concatenate(
-        [np.full(n, 1.0 / r, np.float32) for n, r in zip(sizes, reps)]
-        + [np.zeros(n_padded - n_local, np.float32)])
-    return n_local, n_padded, seg, w
+    starts = np.cumsum([0] + sizes).astype(np.int32)
+    weights = np.array([1.0 / r for r in reps] + [0.0], np.float32)
+    return n_local, n_padded, seg, (starts, weights)
 
 
 def _flatten_local(tree) -> Tuple[jnp.ndarray, Any]:
@@ -296,6 +293,19 @@ def _flatten_local(tree) -> Tuple[jnp.ndarray, Any]:
                             for l in leaves])
     meta = (treedef, [(l.shape, l.dtype) for l in leaves])
     return flat, meta
+
+
+def _pad_with_head(flat: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``flat`` padded to length ``n`` with copies of its head, so the
+    padding codes like the payload: a run of zeros can code past the
+    chunk slot (zero is a rare, long symbol among weights) and overflow
+    the escape pool. The padding is discarded after the all-gather."""
+    pad = n - flat.shape[0]
+    if pad == 0:
+        return flat
+    head = flat[:pad]
+    reps = -(-pad // head.shape[0])
+    return jnp.concatenate([flat, jnp.tile(head, reps)[:pad]])
 
 
 def _unflatten_local(flat: jnp.ndarray, meta) -> Any:
@@ -359,17 +369,6 @@ def make_compressed_step(model_cfg: ModelConfig, opt_cfg: opt.OptConfig,
     :class:`~repro.comm.channel.Channel` objects — one per
     (collective, dp axis) — via :func:`step_channels`.
     """
-    if (model_cfg.moe is not None
-            and model_cfg.moe.impl == "shardmap_a2a"
-            and not hasattr(jax, "shard_map")):
-        raise NotImplementedError(
-            "moe.impl='shardmap_a2a' cannot run inside the compressed "
-            "step on this jax: stage 1 falls back to "
-            "vmap(spmd_axis_name=...), which cannot nest the expert "
-            "shard_map. Use make_baseline_step(..., moe_channels=...) — "
-            "the expert all_to_all still moves QLC containers there — "
-            "or moe.impl='gspmd'/'grouped_local' for compressed "
-            "gradients.")
     loss_fn = _loss_fn(model_cfg, moe_channels=moe_channels)
     dp_axes = dp_axes_in(mesh, train_cfg)
     dp_sizes = {a: mesh.shape[a] for a in dp_axes}
@@ -395,50 +394,31 @@ def make_compressed_step(model_cfg: ModelConfig, opt_cfg: opt.OptConfig,
         lambda s: P(*((dp_axes,) + (None,) * len(tuple(s)))), p_specs,
         is_leaf=lambda s: isinstance(s, P))
     b_spec = batch_pspec(mesh, train_cfg)
-    n_local, n_padded, seg_len, weight_vec = flat_geometry(
+    n_local, n_padded, seg_len, (leaf_starts, leaf_weights) = flat_geometry(
         model_cfg, mesh, train_cfg, comm_cfg)
 
     # ---- stage 1: per-dp-shard gradients (model axis under GSPMD) -------
-    if hasattr(jax, "shard_map"):
-        # New jax: dp axes manual, model axis auto.
-        def grad_body(params, batch):
-            loss, grads = _microbatched_grads(
-                loss_fn, params, batch, train_cfg.microbatches)
-            return loss[None], jax.tree.map(lambda g: g[None], grads)
+    # dp axes manual, model axis auto.
+    def grad_body(params, batch):
+        loss, grads = _microbatched_grads(
+            loss_fn, params, batch, train_cfg.microbatches)
+        return loss[None], jax.tree.map(lambda g: g[None], grads)
 
-        stage1 = _shard_map(
-            grad_body, mesh=mesh,
-            in_specs=(jax.tree.map(lambda s: P(), p_specs,
-                                   is_leaf=lambda s: isinstance(s, P)),
-                      b_spec),
-            out_specs=(P(dp_axes), g_specs_s1),
-            manual_axes=dp_axes)
-    else:
-        # Older jax: partially-auto shard_map trips the XLA SPMD
-        # partitioner; the equivalent classic formulation is a
-        # spmd_axis_name'd vmap over the dp-stacked batch under plain
-        # GSPMD — same per-shard gradients, stacked on the leading dim.
-        def stage1(params, batch):
-            split = jax.tree.map(
-                lambda x: x.reshape(
-                    (dp_total, x.shape[0] // dp_total) + x.shape[1:]),
-                batch)
-
-            def per_shard(mb):
-                with shd.block_axes(dp_axes):
-                    return _microbatched_grads(
-                        loss_fn, params, mb, train_cfg.microbatches)
-
-            return jax.vmap(per_shard, spmd_axis_name=dp_axes)(split)
+    stage1 = shd.shard_map(
+        grad_body, mesh=mesh,
+        in_specs=(jax.tree.map(lambda s: P(), p_specs,
+                               is_leaf=lambda s: isinstance(s, P)),
+                  b_spec),
+        out_specs=(P(dp_axes), g_specs_s1),
+        manual_axes=dp_axes)
 
     # ---- stage 2: hierarchical compressed RS + ZeRO-1 Adam + AG ---------
     def sync_body(params, grads_stacked, flat_opt):
         grads_local = jax.tree.map(lambda g: g[0], grads_stacked)
         g_flat, meta = _flatten_local(grads_local)
         p_flat, _ = _flatten_local(params)
-        pad = n_padded - n_local
-        g_flat = jnp.pad(g_flat, (0, pad))
-        p_flat = jnp.pad(p_flat, (0, pad))
+        g_flat = _pad_with_head(g_flat, n_padded)
+        p_flat = _pad_with_head(p_flat, n_padded)
 
         seg = g_flat
         ok = jnp.bool_(True)
@@ -462,8 +442,11 @@ def make_compressed_step(model_cfg: ModelConfig, opt_cfg: opt.OptConfig,
                if pod_axis is not None else jnp.int32(0))
         for ax in rs_order:
             idx = idx * dp_sizes[ax] + jax.lax.axis_index(ax)
-        w_seg = jax.lax.dynamic_slice(
-            jnp.asarray(weight_vec), (idx * seg_len,), (seg_len,))
+        # The per-leaf weights by position (a full-length weight vector
+        # would be a constant as large as the model).
+        pos = idx * seg_len + jnp.arange(seg_len, dtype=jnp.int32)
+        w_seg = jnp.asarray(leaf_weights)[
+            jnp.searchsorted(jnp.asarray(leaf_starts), pos, side="right") - 1]
         local_sq = jnp.sum(w_seg * jnp.square(seg))
         gnorm = jnp.sqrt(jax.lax.psum(
             local_sq, tuple(dp_axes) + ("model",)))
@@ -515,7 +498,7 @@ def make_compressed_step(model_cfg: ModelConfig, opt_cfg: opt.OptConfig,
     out_specs = (p_specs, opt_state_spec, P(), P(), P())
     if telemetry:
         out_specs += (P(), P())
-    stage2 = _shard_map(
+    stage2 = shd.shard_map(
         sync_body, mesh=mesh,
         in_specs=(p_specs, g_specs, opt_state_spec),
         out_specs=out_specs)
@@ -548,8 +531,11 @@ def init_compressed_opt_state(model_cfg: ModelConfig, mesh: Mesh,
     dp_axes = dp_axes_in(mesh, train_cfg)
     lead = tuple(mesh.shape[a] for a in dp_axes) + (mesh.shape["model"],)
     dt = jnp.dtype(opt_cfg.moment_dtype)
+    # Each rank's segment is made on its own device (the step's
+    # opt_state_spec), never gathered whole on one.
+    owned = NamedSharding(mesh, P(*(dp_axes + ("model", None))))
     return {
-        "m": jnp.zeros(lead + (seg,), dt),
-        "v": jnp.zeros(lead + (seg,), dt),
-        "step": jnp.zeros((), jnp.int32),
+        "m": jnp.zeros(lead + (seg,), dt, device=owned),
+        "v": jnp.zeros(lead + (seg,), dt, device=owned),
+        "step": jnp.zeros((), jnp.int32, device=NamedSharding(mesh, P())),
     }

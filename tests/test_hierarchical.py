@@ -351,7 +351,7 @@ from repro.comm.calibrate import histogram_of_tree
 from repro.configs import get_config, reduced
 from repro.core import CodecRegistry
 from repro.data import DataConfig, SyntheticDataset
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_device_mesh
 from repro.models import init_params
 from repro.parallel import sharding as shd
 from repro.training import (OptConfig, TrainConfig,
@@ -359,7 +359,7 @@ from repro.training import (OptConfig, TrainConfig,
                             make_compressed_step)
 
 cfg = reduced(get_config("gemma-2b-sft"))
-mesh = make_test_mesh(pods=2)
+mesh = make_device_mesh(pods=2)
 assert mesh.axis_names == ("pod", "data", "model"), mesh.axis_names
 opt_cfg = OptConfig(lr=3e-4, total_steps=4, warmup_steps=1)
 train_cfg = TrainConfig(batch_axes=("pod", "data"))

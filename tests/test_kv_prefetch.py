@@ -23,7 +23,7 @@ from repro.models import init_decode_states, init_params
 from repro.serving import (ArenaStale, BlockArena, KVCacheSpec,
                            PagedKVCache, ServeConfig, calibrate_cache,
                            prefill)
-from repro.serving.engine import _generate_scanned, _window_step
+from repro.serving.engine import _decode_window, _generate_scanned
 from repro.serving.kv_cache import (calibration_arrays,
                                     device_byte_planes,
                                     device_symbol_stream)
@@ -235,12 +235,11 @@ class TestAsyncEngine:
         """The probe behind the engine's counters: a whole 8-token
         window dispatches under ``jax.transfer_guard("disallow")`` —
         any per-token host callback or implicit transfer inside the
-        scan would raise."""
+        window would raise."""
         cfg, params, _ = setup
         states = init_decode_states(cfg, 2, 64)
         tok = jnp.zeros((2, 1), jnp.int32)
         pos = jnp.zeros((2, 1), jnp.int32)
-        wf = _window_step(cfg, 8)
         with jax.transfer_guard("disallow"):
-            toks, states = wf(params, tok, pos, states)
+            toks, states = _decode_window(cfg, params, tok, pos, states, 8)
         assert np.asarray(toks).shape == (2, 8)

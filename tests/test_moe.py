@@ -186,24 +186,6 @@ class TestCalibration:
                    for n in entries)
 
 
-def test_compressed_step_rejects_shardmap_a2a_on_old_jax():
-    if hasattr(jax, "shard_map"):
-        pytest.skip("new jax: stage 1 nests the expert shard_map fine")
-    from jax.sharding import Mesh
-    from repro.core.registry import CodecRegistry
-    from repro.training import train_step as ts
-    cfg = dataclasses.replace(reduced(get_config("deepseek-moe-16b")),
-                              moe=dataclasses.replace(
-                                  reduced(get_config(
-                                      "deepseek-moe-16b")).moe,
-                                  impl="shardmap_a2a"))
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
-    with pytest.raises(NotImplementedError, match="make_baseline_step"):
-        ts.make_compressed_step(cfg, None, ts.TrainConfig(), mesh,
-                                CodecRegistry())
-
-
 MD_PARITY = r"""
 import contextlib
 import dataclasses
