@@ -263,8 +263,12 @@ def _paged_step(cfg: ModelConfig):
 @functools.lru_cache(maxsize=8)
 def _prefill_fn(cfg: ModelConfig):
     """Jitted prefill, cached per config (the engine's admission path;
-    jit re-specializes per prompt length)."""
-    return jax.jit(lambda p, tokens, st: prefill(p, cfg, tokens, st))
+    jit re-specializes per prompt length). Its executable is
+    ``jit_prefill`` in a device trace."""
+    def run(p, tokens, st):
+        return prefill(p, cfg, tokens, st)
+    run.__name__ = "prefill"
+    return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=8)
@@ -274,9 +278,12 @@ def _prefill_from_fn(cfg: ModelConfig):
     boundary-state snapshots (``KVCacheSpec.ssm_rebase``) can be
     captured between segments. Feeding a prompt in segments through
     this is state-identical to one whole-prompt :func:`prefill` call
-    (same scan body, same positions)."""
-    return jax.jit(lambda p, tokens, st, start: prefill(
-        p, cfg, tokens, st, start_pos=start))
+    (same scan body, same positions). Its executable is
+    ``jit_prefill_from``."""
+    def run(p, tokens, st, start):
+        return prefill(p, cfg, tokens, st, start_pos=start)
+    run.__name__ = "prefill_from"
+    return jax.jit(run)
 
 
 def _decode_window(cfg: ModelConfig, params, tok, pos, states,

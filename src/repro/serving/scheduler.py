@@ -34,6 +34,18 @@ Scheduling model (all host-side, fully deterministic):
   block is read back FROM the pooled container, so shared bytes are on
   the token hot path, not a shadow copy.
 
+Profiler spans (``jax.profiler.TraceAnnotation``, a few per step and
+per block, cheap while no trace records) mark where the host spends an
+engine step, nested on the calling thread: ``engine.step`` holds
+``engine.admit`` (holding ``engine.prefill``, ``kv.calibrate`` and
+``engine.slot_write``), ``engine.decode`` and ``engine.page``; a sync
+``engine.page`` holds, for each state slot ``l{i}``, ``kv.encode``,
+``pool.put``, ``kv.decode``, ``kv.restore``, then one
+``engine.slot_write``. ``rid`` ties the spans of one request together.
+``stats()["kv"]`` counts the blocks paged through the host path, the
+wall seconds they took, and the dense and wire bytes of every pooled
+block.
+
 The legacy ``generate`` / ``generate_paged`` / ``generate_from_wire``
 functions are deprecated wrappers building a one-engine run
 (``repro.serving.engine``), asserted token-identical to the scan-based
@@ -50,6 +62,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.comm.blockpool import (ArenaExhausted, BlockArena, BlockPool,
                                   PoolExhausted)
@@ -216,6 +229,10 @@ class Engine:
         self._prefill_tokens = 0
         self._decode_s = 0.0
         self._decode_tokens = 0
+        self._blocks_paged = 0      # blocks through _evict_slot
+        self._page_s = 0.0          # wall seconds inside _evict_slot
+        self._pooled_dense_bytes = 0    # summed over every _pool_put
+        self._pooled_wire_bytes = 0
         self._dense_of: Dict[str, int] = {}     # digest -> dense bytes
         self._dense_logical = 0
         self.peak_dense_logical_bytes = 0
@@ -250,9 +267,16 @@ class Engine:
         active set (one admission *window* of steps under
         ``kv_paging="async"``), page completed blocks. Returns the
         number of requests still in flight (waiting + running)."""
-        if self.kv_paging == "async":
-            return self._step_async()
         self._step_idx += 1
+        with TraceAnnotation("engine.step", step=self._step_idx):
+            if self.kv_paging == "async":
+                self._step_async()
+            else:
+                self._step_sync()
+        return sum(1 for s in self._seqs.values()
+                   if s.state in ("waiting", "running"))
+
+    def _step_sync(self):
         self._admit()
         active = [(b, rid) for b, rid in enumerate(self._slots)
                   if rid is not None]
@@ -264,10 +288,11 @@ class Engine:
                 tokens[b, 0] = seq.toks[-1]
                 pos[b, 0] = seq.prompt_len + len(seq.toks) - 1
             t0 = time.perf_counter()
-            nxt, _, self._states = self._step_fn(
-                self.params, jnp.asarray(tokens), self._states,
-                jnp.asarray(pos))
-            nxt = np.asarray(nxt)           # forces the dispatch
+            with TraceAnnotation("engine.decode", active=len(active)):
+                nxt, _, self._states = self._step_fn(
+                    self.params, jnp.asarray(tokens), self._states,
+                    jnp.asarray(pos))
+                nxt = np.asarray(nxt)       # forces the dispatch
             self._decode_s += time.perf_counter() - t0
             self._decode_tokens += len(active)
             for b, rid in active:
@@ -281,10 +306,8 @@ class Engine:
                     continue
                 if len(seq.toks) >= seq.req.max_new_tokens:
                     self._finish(seq)
-        return sum(1 for s in self._seqs.values()
-                   if s.state in ("waiting", "running"))
 
-    def _step_async(self) -> int:
+    def _step_async(self):
         """One *admission window* of decode steps
         (``engine._decode_window``): the host uploads one seed token +
         position per slot, the greedy feedback stays on device, and
@@ -295,7 +318,6 @@ class Engine:
         snapshots) only ever happen between windows; the prefetch
         decodes scheduled there are consumed after the NEXT window's
         result lands, which is what hides them behind model compute."""
-        self._step_idx += 1
         self._admit()
         active = [(b, rid) for b, rid in enumerate(self._slots)
                   if rid is not None]
@@ -322,22 +344,24 @@ class Engine:
                 tokens[b, 0] = seq.toks[-1]
                 pos[b, 0] = seq.prompt_len + len(seq.toks) - 1
             t0 = time.perf_counter()
-            tok_dev = jnp.asarray(tokens)
-            pos_dev = jnp.asarray(pos)
-            self._window_h2d += 2
-            with jax.transfer_guard("disallow"):
-                # The probe: any per-token host transfer inside the
-                # window would raise here.
-                gen_dev, self._states = _decode_window(
-                    self.cfg, self.params, tok_dev, pos_dev,
-                    self._states, window)
-            gen = np.asarray(gen_dev)       # ONE d2h for the window
-            self._window_d2h += 1
-            self._windows += 1
-            # Last boundary's prefetch decodes ran behind this window
-            # on the in-order device stream — wait on them now (timed:
-            # a stall here is the cost prefetch failed to hide) ...
-            ready = self._consume_pending()
+            with TraceAnnotation("engine.decode", active=len(active)):
+                tok_dev = jnp.asarray(tokens)
+                pos_dev = jnp.asarray(pos)
+                self._window_h2d += 2
+                with jax.transfer_guard("disallow"):
+                    # The probe: any per-token host transfer inside the
+                    # window would raise here.
+                    gen_dev, self._states = _decode_window(
+                        self.cfg, self.params, tok_dev, pos_dev,
+                        self._states, window)
+                gen = np.asarray(gen_dev)   # ONE d2h for the window
+                self._window_d2h += 1
+                self._windows += 1
+                # Last boundary's prefetch decodes ran behind this
+                # window on the in-order device stream — wait on them
+                # now (timed: a stall here is the cost prefetch failed
+                # to hide) ...
+                ready = self._consume_pending()
             self._decode_s += time.perf_counter() - t0
             self._decode_tokens += len(active) * window
             # ... and apply them untimed, like the sync path's _page.
@@ -355,8 +379,6 @@ class Engine:
                     continue
                 if len(seq.toks) >= seq.req.max_new_tokens:
                     self._finish(seq)
-        return sum(1 for s in self._seqs.values()
-                   if s.state in ("waiting", "running"))
 
     def run(self):
         """Drive :meth:`step` until every submitted request finished or
@@ -408,8 +430,31 @@ class Engine:
         return mean * n_blocks * len(self._kinds)
 
     def _start(self, seq: _Seq):
-        b = self._slots.index(None)
-        t0 = time.perf_counter()
+        with TraceAnnotation("engine.admit", rid=seq.rid,
+                             prompt_len=seq.prompt_len):
+            b = self._slots.index(None)
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.prefill", rid=seq.rid,
+                                 tokens=seq.prompt_len):
+                first, row = self._prefill_row(seq)
+            self._prefill_s += time.perf_counter() - t0
+            self._prefill_tokens += seq.prompt_len
+            if self.kv_spec is not None and self._codec is None:
+                self._ensure_codec(row, seq.prompt_len)
+            self._write_slot(seq, b, row)
+            self._slots[b] = seq.rid
+            seq.slot = b
+            seq.state = "running"
+            seq.toks = [first]
+            self._log("admit", seq.rid)
+            self._page(seq)                 # prompt blocks page out now
+            if len(seq.toks) >= seq.req.max_new_tokens:
+                self._finish(seq)
+
+    def _prefill_row(self, seq: _Seq):
+        """Prefill ``seq``'s prompt into a fresh batch-1 row; returns
+        its first token (read back, so the prefill has finished) and
+        the row."""
         row = init_decode_states(self.cfg, 1, self.max_seq_len)
         if self._rebase:
             # Segmented prefill: pause at every block boundary to
@@ -430,32 +475,25 @@ class Engine:
         else:
             prompts = jnp.asarray(seq.req.prompt[None, :])
             logits, row = self._prefill(self.params, prompts, row)
-        first = int(np.argmax(np.asarray(logits)[0]))
-        self._prefill_s += time.perf_counter() - t0
-        self._prefill_tokens += seq.prompt_len
-        if self.kv_spec is not None and self._codec is None:
-            self._ensure_codec(row, seq.prompt_len)
-        self._states = _slot_write(self._states, b, row)
-        self._slots[b] = seq.rid
-        seq.slot = b
-        seq.state = "running"
-        seq.toks = [first]
-        self._log("admit", seq.rid)
-        self._page(seq)                     # prompt blocks page out now
-        if len(seq.toks) >= seq.req.max_new_tokens:
-            self._finish(seq)
+        return int(np.argmax(np.asarray(logits)[0])), row
+
+    def _write_slot(self, seq: _Seq, b: int, row):
+        """Scatter a batch-1 row into slot ``b`` of the decode states."""
+        with TraceAnnotation("engine.slot_write", rid=seq.rid):
+            self._states = _slot_write(self._states, b, row)
 
     def _ensure_codec(self, row_states, tokens: int):
         """Build the shared block codec, calibrating the registry's
         ``kv/layer{i}`` entries from the first prefill when absent."""
-        base = self.kv_spec.layer_codec(0)
-        have = any(n == base or n.startswith(base + "/")
-                   for n in self.registry.names())
-        if not have:
-            calibrate_cache(self.registry, self.cfg, row_states, tokens,
-                            self.kv_spec)
-        self._codec = PagedKVCache(self.kv_spec, self.cfg, self.registry,
-                                   mesh=self._mesh)
+        with TraceAnnotation("kv.calibrate"):
+            base = self.kv_spec.layer_codec(0)
+            have = any(n == base or n.startswith(base + "/")
+                       for n in self.registry.names())
+            if not have:
+                calibrate_cache(self.registry, self.cfg, row_states,
+                                tokens, self.kv_spec)
+            self._codec = PagedKVCache(self.kv_spec, self.cfg,
+                                       self.registry, mesh=self._mesh)
 
     # ---- paging through the shared pool ---------------------------------
 
@@ -494,52 +532,61 @@ class Engine:
         """Encode one completed block of ``seq``'s slot row into the
         pool, then restore the row from the POOLED container — shared
         (deduped) bytes are what the model attends over."""
-        row = _slot_view(self._states, seq.slot)
-        new_row = dict(row)
-        bsnap = (self._snaps.take(seq.rid, t1) if self._rebase else None)
-        for i, kind in enumerate(self._kinds):
-            key = f"l{i}"
-            name = self.kv_spec.layer_codec(i)
-            st = row[key]
-            if kind == "attention":
-                k, v = attn.kv_block_slice(st, t0, t1)
-                block = self._codec.encode_block_arrays(
-                    name, key, (k, v), start=t0, tokens=t1 - t0)
-                digest = self._pool_put(seq, block)
-                k2, v2 = self._codec.decode_block_arrays(
-                    self.pool.get(digest))
-                new_row[key] = attn.kv_block_restore(
-                    st, t0, t1, jnp.asarray(k2), jnp.asarray(v2))
-            elif bsnap is not None and key in bsnap:
-                # Re-based snapshot: the state AT boundary t1 — depends
-                # only on tokens < t1, so shared prompt prefixes pool
-                # to identical digests. The live state (which has
-                # absorbed tokens past t1) is left untouched; the
-                # decode still runs so an overflowing container
-                # surfaces here, not on a later reader.
-                block = self._codec.encode_block_arrays(
-                    name, key, bsnap[key], start=t1, tokens=t1 - t0)
-                digest = self._pool_put(seq, block)
-                self._codec.decode_block_arrays(self.pool.get(digest))
-                old = seq.snap_digests.get(key)
-                if old is not None:
-                    self._pool_release(seq, old)
-                seq.snap_digests[key] = digest
-            else:
-                arrays = ssm.state_snapshot(st)
-                block = self._codec.encode_block_arrays(
-                    name, key, arrays, start=t1, tokens=t1 - t0)
-                digest = self._pool_put(seq, block)
-                decoded = [jnp.asarray(a) for a in
-                           self._codec.decode_block_arrays(
-                               self.pool.get(digest))]
-                new_row[key] = ssm.state_restore(st, decoded)
+        tick = time.perf_counter()
+        with TraceAnnotation("engine.page", rid=seq.rid, start=t0):
+            row = _slot_view(self._states, seq.slot)
+            new_row = dict(row)
+            bsnap = (self._snaps.take(seq.rid, t1) if self._rebase
+                     else None)
+            for i, kind in enumerate(self._kinds):
+                key = f"l{i}"
+                st = row[key]
+                if kind == "attention":
+                    _, (k2, v2) = self._code_block(
+                        seq, i, attn.kv_block_slice(st, t0, t1),
+                        start=t0, tokens=t1 - t0)
+                    with TraceAnnotation("kv.restore", layer=key):
+                        new_row[key] = attn.kv_block_restore(
+                            st, t0, t1, jnp.asarray(k2), jnp.asarray(v2))
+                    continue
+                if bsnap is not None and key in bsnap:
+                    # Re-based snapshot: the state AT boundary t1 —
+                    # depends only on tokens < t1, so shared prompt
+                    # prefixes pool to identical digests. The live state
+                    # (which has absorbed tokens past t1) is left
+                    # untouched; the decode still runs so an overflowing
+                    # container surfaces here, not on a later reader.
+                    digest, _ = self._code_block(
+                        seq, i, bsnap[key], start=t1, tokens=t1 - t0)
+                else:
+                    digest, decoded = self._code_block(
+                        seq, i, ssm.state_snapshot(st), start=t1,
+                        tokens=t1 - t0)
+                    with TraceAnnotation("kv.restore", layer=key):
+                        new_row[key] = ssm.state_restore(
+                            st, [jnp.asarray(a) for a in decoded])
                 # the newest snapshot supersedes the previous one
                 old = seq.snap_digests.get(key)
                 if old is not None:
                     self._pool_release(seq, old)
                 seq.snap_digests[key] = digest
-        self._states = _slot_write(self._states, seq.slot, new_row)
+            self._write_slot(seq, seq.slot, new_row)
+        self._blocks_paged += 1
+        self._page_s += time.perf_counter() - tick
+
+    def _code_block(self, seq: _Seq, i: int, arrays, *, start: int,
+                    tokens: int):
+        """Encode layer ``i``'s block, pool it, and decode it back FROM
+        the pooled container; returns ``(digest, decoded arrays)``."""
+        key = f"l{i}"
+        with TraceAnnotation("kv.encode", layer=key):
+            block = self._codec.encode_block_arrays(
+                self.kv_spec.layer_codec(i), key, arrays, start=start,
+                tokens=tokens)
+        digest = self._pool_put(seq, block)
+        with TraceAnnotation("kv.decode", layer=key):
+            decoded = self._codec.decode_block_arrays(self.pool.get(digest))
+        return digest, decoded
 
     # ---- async paging (device-resident arena + prefetch) -----------------
 
@@ -568,43 +615,44 @@ class Engine:
         compute instead of on the block-boundary critical path. Escape
         overflow under the plan capacity falls back to the sync host
         path for the whole boundary (counted as a prefetch miss)."""
-        row = _slot_view(self._states, seq.slot)
-        bsnap = (self._snaps.take(seq.rid, t1) if self._rebase else None)
-        devs = []
-        for i, kind in enumerate(self._kinds):
-            key = f"l{i}"
-            name = self.kv_spec.layer_codec(i)
-            st = row[key]
-            if kind == "attention":
-                arrays = attn.kv_block_slice(st, t0, t1)
-                start = t0
-            elif bsnap is not None and key in bsnap:
-                arrays = bsnap[key]
-                start = t1
-            else:
-                arrays = ssm.state_snapshot(st)
-                start = t1
-            dev = self._codec.encode_block_device(
-                name, key, arrays, start=start, tokens=t1 - t0)
-            if dev is None:
-                # plan-capacity escape overflow: redo this boundary on
-                # the host sync path (re-wires the section raw there)
-                self._codec.prefetcher.miss()
-                if bsnap is not None:
-                    self._snaps.record(seq.rid, t1, bsnap)  # un-take
-                self._evict_slot(seq, t0, t1)
-                return
-            devs.append(dev)
-        arena = self._ensure_arena(max(d.plan.total_words for d in devs))
-        for dev in devs:
-            try:
-                slot, gen = arena.alloc()
-                arena.write(slot, dev.words)
-                dev.slot, dev.gen = slot, gen
-            except ArenaExhausted:
-                dev.slot = None     # decode straight from the HBM words
-            self._pending.append(
-                (seq.rid, self._codec.prefetcher.schedule(dev)))
+        with TraceAnnotation("engine.page", rid=seq.rid, start=t0):
+            row = _slot_view(self._states, seq.slot)
+            bsnap = (self._snaps.take(seq.rid, t1) if self._rebase else None)
+            devs = []
+            for i, kind in enumerate(self._kinds):
+                key = f"l{i}"
+                name = self.kv_spec.layer_codec(i)
+                st = row[key]
+                if kind == "attention":
+                    arrays = attn.kv_block_slice(st, t0, t1)
+                    start = t0
+                elif bsnap is not None and key in bsnap:
+                    arrays = bsnap[key]
+                    start = t1
+                else:
+                    arrays = ssm.state_snapshot(st)
+                    start = t1
+                dev = self._codec.encode_block_device(
+                    name, key, arrays, start=start, tokens=t1 - t0)
+                if dev is None:
+                    # plan-capacity escape overflow: redo this boundary on
+                    # the host sync path (re-wires the section raw there)
+                    self._codec.prefetcher.miss()
+                    if bsnap is not None:
+                        self._snaps.record(seq.rid, t1, bsnap)  # un-take
+                    self._evict_slot(seq, t0, t1)
+                    return
+                devs.append(dev)
+            arena = self._ensure_arena(max(d.plan.total_words for d in devs))
+            for dev in devs:
+                try:
+                    slot, gen = arena.alloc()
+                    arena.write(slot, dev.words)
+                    dev.slot, dev.gen = slot, gen
+                except ArenaExhausted:
+                    dev.slot = None     # decode straight from the HBM words
+                self._pending.append(
+                    (seq.rid, self._codec.prefetcher.schedule(dev)))
 
     def _consume_pending(self):
         """Wait on the prefetch decodes scheduled at the last boundary:
@@ -654,7 +702,7 @@ class Engine:
             full[dev.layer] = attn.kv_block_restore(
                 full[dev.layer], dev.start, dev.start + dev.tokens,
                 k2, v2)
-            self._states = _slot_write(self._states, seq.slot, full)
+            self._write_slot(seq, seq.slot, full)
         else:
             # SSM: never restore — the live state has advanced past the
             # snapshot boundary. Supersede the previous snapshot.
@@ -678,8 +726,11 @@ class Engine:
         self._pending = keep
 
     def _pool_put(self, seq: _Seq, block) -> str:
-        digest = self.pool.put(block)
+        with TraceAnnotation("pool.put", layer=block.layer):
+            digest = self.pool.put(block)
         seq.digests.append(digest)
+        self._pooled_dense_bytes += block.dense_bytes
+        self._pooled_wire_bytes += block.wire_bytes
         self._dense_of[digest] = block.dense_bytes
         self._dense_logical += block.dense_bytes
         self.peak_dense_logical_bytes = max(self.peak_dense_logical_bytes,
@@ -737,7 +788,13 @@ class Engine:
         (the speed.md reporting format), KV codec counters, and the
         pool's byte-level stats (with ``dense_logical`` rows so the
         capacity win — dense bytes a dense cache would pin vs pooled
-        compressed bytes — is one division away)."""
+        compressed bytes — is one division away).
+
+        ``kv`` (paged engines, once the codec exists) is cumulative:
+        ``blocks_paged`` and ``page_s`` count the blocks paged through
+        the host path (:meth:`_evict_slot`) and their wall seconds;
+        ``dense_bytes`` and ``wire_bytes`` sum every block put into the
+        pool, dedup hits included."""
         by_state: Dict[str, int] = {}
         for s in self._seqs.values():
             by_state[s.state] = by_state.get(s.state, 0) + 1
@@ -758,6 +815,10 @@ class Engine:
             out["kv"] = {
                 "overflow_sections": self._codec.overflow_sections,
                 "raw_sections": self._codec.raw_sections,
+                "blocks_paged": self._blocks_paged,
+                "page_s": self._page_s,
+                "dense_bytes": self._pooled_dense_bytes,
+                "wire_bytes": self._pooled_wire_bytes,
             }
         if self.kv_paging == "async":
             out["async"] = {
