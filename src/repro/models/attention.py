@@ -210,10 +210,13 @@ def kv_block_restore(cache: KVCache, t0: int, t1: int,
 
 def attention_block(params, x, cfg: ModelConfig, positions,
                     cache: Optional[KVCache] = None):
-    """Self-attention (training/prefill) or single-token decode.
+    """Self-attention (training) or attention through a decode cache.
 
-    x: [B, S, D]. With ``cache``, S==1 decode: append to cache, attend
-    over the filled prefix. Returns (out [B,S,D], new_cache|None).
+    x: [B, S, D]. With ``cache``, write the S new keys and values at
+    ``cache.length`` (the caller keeps ``positions`` starting there)
+    and attend causally over the filled prefix: S == 1 is a decode
+    step, S > 1 a one-pass prefill. Returns (out [B,S,D],
+    new_cache|None).
     """
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     groups = h // kv
@@ -247,7 +250,7 @@ def attention_block(params, x, cfg: ModelConfig, positions,
             k_new = jnp.where(writing, k.astype(cache.k.dtype), cache.k)
             v_new = jnp.where(writing, v.astype(cache.v.dtype), cache.v)
         else:
-            # Multi-token prefill into the cache (small-scale serving).
+            # One-pass prefill: the S tokens land at [length, length+S).
             k_new = jax.vmap(
                 lambda ck, kn, i: jax.lax.dynamic_update_slice(
                     ck, kn.astype(ck.dtype), (i, 0, 0)))(cache.k, k, idx)

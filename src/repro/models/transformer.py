@@ -303,16 +303,19 @@ def decode_states_specs(cfg: ModelConfig):
 
 def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                 states, positions: jnp.ndarray, weight_codec=None):
-    """One-token decode. tokens: [B, 1]; positions: [B, 1] absolute.
+    """Decode through the state caches. tokens: [B, S]; positions:
+    [B, S] absolute (S == 1 for a decode step; S > 1 writes S tokens
+    into the attention caches at once, see ``attention_block``).
 
-    Returns (logits [B, 1, V], new_states).
+    Returns (logits [B, 1, V] of the last position, new_states): the
+    final norm and head run on that position alone.
     """
     dtype = jnp.dtype(cfg.dtype)
     x = layers.embed(params["embed"], tokens).astype(dtype)
     x = logical_constraint(x, ("batch", "seq", "embed"))
     x, new_states = apply_stack(params, x, positions, cfg, states=states,
                                 weight_codec=weight_codec)
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = layers.rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
     logits = layers.unembed(head, x, cfg.tie_embeddings)
     return logits, new_states

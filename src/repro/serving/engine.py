@@ -1,9 +1,11 @@
 """Batched serving: prefill + decode with per-layer state caches.
 
-``prefill`` runs the full-sequence forward once per layer while
-collecting KV/SSM states (token-by-token scan for recurrent blocks,
-bulk write for attention); ``generate`` then decodes greedily. The
-decode step is the function the decode_* dry-run cells lower.
+``prefill`` fills the per-layer KV/SSM states from a prompt: in one
+forward pass that writes every key and value into the attention caches
+where all layers are attention with dense FFNs, else by scanning the
+decode step over the tokens (``prefill_mode`` says which);
+``generate`` then decodes greedily. The decode step is the function
+the decode_* dry-run cells lower.
 
 Compressed-weight serving: ``compress_params_for_serving`` stores the
 parameter stack as block-32 e4m3 + QLC words (``repro.comm.weights``)
@@ -54,10 +56,44 @@ class ServeConfig:
     greedy: bool = True
 
 
+def prefill_mode(cfg: ModelConfig) -> str:
+    """How :func:`prefill` runs a prompt under ``cfg``: ``"bulk"`` (one
+    forward pass) where every layer is attention with a dense FFN,
+    ``"serial"`` (one decode step a token) otherwise. Recurrent layers
+    need the scan; MoE layers would route all S tokens at once under
+    the capacity factor's drops, which is a different result."""
+    kinds = cfg.layer_kinds()
+    if all(k == "attention" for k in kinds) and all(
+            cfg.ffn_kind(i) != "moe" for i in range(len(kinds))):
+        return "bulk"
+    return "serial"
+
+
 def prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
-            states, start_pos: int = 0):
-    """Feed a prompt through the decode path token by token (reference
-    implementation — correct for every block kind incl. recurrent).
+            states, start_pos=0):
+    """Feed a prompt into the decode states, starting at ``start_pos``
+    (each row's cache ``length`` must equal it).
+
+    In ``"bulk"`` mode (:func:`prefill_mode`) one forward pass writes
+    all S keys and values into the attention caches and masks causally
+    per query; the final norm and head run on the last position only.
+    Otherwise :func:`prefill_serial` scans the decode step over the
+    tokens. tokens: [B, S]. Returns (last_logits [B, V], states).
+    """
+    if prefill_mode(cfg) == "serial":
+        return prefill_serial(params, cfg, tokens, states, start_pos)
+    b, s = tokens.shape
+    pos = jnp.asarray(start_pos, jnp.int32) + jnp.broadcast_to(
+        jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    logits, states = decode_step(params, cfg, tokens, states, pos)
+    return logits[:, 0], states
+
+
+def prefill_serial(params, cfg: ModelConfig, tokens: jnp.ndarray,
+                   states, start_pos=0):
+    """Feed a prompt through the decode step token by token — correct
+    for every block kind, recurrent and MoE included, and the reference
+    the one-pass :func:`prefill` is tested against.
 
     tokens: [B, S]. Returns (last_logits [B, V], states).
     """
@@ -66,17 +102,13 @@ def prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
     def body(carry, t):
         st = carry
         tok_t = jax.lax.dynamic_slice_in_dim(tokens, t, 1, axis=1)
-        lg, st = _one(params, cfg, tok_t,
-                      jnp.full((b, 1), start_pos, jnp.int32) + t, st)
+        lg, st = decode_step(params, cfg, tok_t, st,
+                             jnp.full((b, 1), start_pos, jnp.int32) + t)
         return st, lg[:, 0]
 
     states, logits_seq = jax.lax.scan(
         body, states, jnp.arange(s, dtype=jnp.int32))
     return logits_seq[-1], states
-
-
-def _one(params, cfg, tok, pos, states):
-    return decode_step(params, cfg, tok, states, pos)
 
 
 def _generate_scanned(params, cfg: ModelConfig, prompts: jnp.ndarray,
@@ -262,9 +294,10 @@ def _paged_step(cfg: ModelConfig):
 
 @functools.lru_cache(maxsize=8)
 def _prefill_fn(cfg: ModelConfig):
-    """Jitted prefill, cached per config (the engine's admission path;
-    jit re-specializes per prompt length). Its executable is
-    ``jit_prefill`` in a device trace."""
+    """Jitted :func:`prefill`, cached per config (the engine's
+    admission path; jit re-specializes per prompt length). Its
+    executable is ``jit_prefill`` in a device trace, whichever
+    :func:`prefill_mode` the config takes."""
     def run(p, tokens, st):
         return prefill(p, cfg, tokens, st)
     run.__name__ = "prefill"
@@ -276,10 +309,10 @@ def _prefill_from_fn(cfg: ModelConfig):
     """Jitted prefill accepting a start position — the engine's
     *segmented* prefill, which pauses at block boundaries so the SSM
     boundary-state snapshots (``KVCacheSpec.ssm_rebase``) can be
-    captured between segments. Feeding a prompt in segments through
-    this is state-identical to one whole-prompt :func:`prefill` call
-    (same scan body, same positions). Its executable is
-    ``jit_prefill_from``."""
+    captured between segments. Each segment starts where the cache's
+    ``length`` stands, so feeding a prompt in segments through this
+    computes what one whole-prompt :func:`prefill` call does (the same
+    mode, the same positions). Its executable is ``jit_prefill_from``."""
     def run(p, tokens, st, start):
         return prefill(p, cfg, tokens, st, start_pos=start)
     run.__name__ = "prefill_from"

@@ -32,7 +32,10 @@ Scheduling model (all host-side, fully deterministic):
   dedup by container digest (prefix sharing) and diverge copy-on-write
   (immutable blocks, new digests past the split point). Every decoded
   block is read back FROM the pooled container, so shared bytes are on
-  the token hot path, not a shadow copy.
+  the token hot path, not a shadow copy. Sync paging codes at most one
+  block a slot per step: an admitted prompt's blocks page out over the
+  steps that follow, so one long prompt does not stall every slot for
+  the whole of its paging.
 
 Profiler spans (``jax.profiler.TraceAnnotation``, a few per step and
 per block, cheap while no trace records) mark where the host spends an
@@ -41,7 +44,10 @@ engine step, nested on the calling thread: ``engine.step`` holds
 ``engine.slot_write``), ``engine.decode`` and ``engine.page``; a sync
 ``engine.page`` holds, for each state slot ``l{i}``, ``kv.encode``,
 ``pool.put``, ``kv.decode``, ``kv.restore``, then one
-``engine.slot_write``. ``rid`` ties the spans of one request together.
+``engine.slot_write``. ``rid`` ties the spans of one request together;
+``engine.prefill`` carries the prefill's ``mode`` (``"bulk"`` or
+``"serial"``, ``engine.prefill_mode``), and ``stats()``
+``prefill_bulk_tokens`` counts the prompt tokens of bulk prefills.
 ``stats()["kv"]`` counts the blocks paged through the host path, the
 wall seconds they took, and the dense and wire bytes of every pooled
 block.
@@ -70,7 +76,8 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import init_decode_states, ssm
 from repro.serving.engine import (_decode_window, _paged_step,
-                                  _prefill_fn, _prefill_from_fn)
+                                  _prefill_fn, _prefill_from_fn,
+                                  prefill_mode)
 from repro.serving.kv_cache import (KVCacheSpec, PagedKVCache,
                                     SSMBoundaryTracker, calibrate_cache)
 
@@ -210,6 +217,7 @@ class Engine:
         self._step_fn = _paged_step(cfg)
         self._prefill = _prefill_fn(cfg)
         self._prefill_from = _prefill_from_fn(cfg)
+        self._prefill_mode = prefill_mode(cfg)
         self.kv_paging = kv_paging
         self._arena_slots = int(arena_slots)
         #: boundary-state snapshots for SSM re-basing (qlc only)
@@ -227,6 +235,7 @@ class Engine:
         self._step_idx = 0
         self._prefill_s = 0.0
         self._prefill_tokens = 0
+        self._prefill_bulk_tokens = 0   # of them, in one forward pass
         self._decode_s = 0.0
         self._decode_tokens = 0
         self._blocks_paged = 0      # blocks through _evict_slot
@@ -300,7 +309,7 @@ class Engine:
                 seq.toks.append(int(nxt[b, 0]))
                 self._note_boundary(seq)
                 try:
-                    self._page(seq)
+                    self._page(seq, most=1)
                 except PoolExhausted as e:
                     self._reject(seq, e)
                     continue
@@ -435,10 +444,13 @@ class Engine:
             b = self._slots.index(None)
             t0 = time.perf_counter()
             with TraceAnnotation("engine.prefill", rid=seq.rid,
-                                 tokens=seq.prompt_len):
+                                 tokens=seq.prompt_len,
+                                 mode=self._prefill_mode):
                 first, row = self._prefill_row(seq)
             self._prefill_s += time.perf_counter() - t0
             self._prefill_tokens += seq.prompt_len
+            if self._prefill_mode == "bulk":
+                self._prefill_bulk_tokens += seq.prompt_len
             if self.kv_spec is not None and self._codec is None:
                 self._ensure_codec(row, seq.prompt_len)
             self._write_slot(seq, b, row)
@@ -447,7 +459,9 @@ class Engine:
             seq.state = "running"
             seq.toks = [first]
             self._log("admit", seq.rid)
-            self._page(seq)                 # prompt blocks page out now
+            if self.kv_paging == "async":
+                # windows end at block boundaries: page the prompt now
+                self._page(seq)
             if len(seq.toks) >= seq.req.max_new_tokens:
                 self._finish(seq)
 
@@ -459,8 +473,8 @@ class Engine:
         if self._rebase:
             # Segmented prefill: pause at every block boundary to
             # capture the recurrent layers' boundary states (the
-            # re-basing snapshots). State-identical to one whole-prompt
-            # prefill — same scan body, same positions.
+            # re-basing snapshots). The same computation as one
+            # whole-prompt prefill — same mode, same positions.
             bt = self.kv_spec.block_tokens
             prompt = seq.req.prompt
             logits, pos = None, 0
@@ -497,17 +511,25 @@ class Engine:
 
     # ---- paging through the shared pool ---------------------------------
 
-    def _page(self, seq: _Seq):
+    def _page(self, seq: _Seq, most: Optional[int] = None):
+        """Page out ``seq``'s completed blocks, oldest first: all of
+        them, or ``most`` at most. Sync decode pages one a step, so a
+        long prompt's blocks page out over the steps after its
+        admission instead of stalling every slot in one (a decode step
+        completes at most one block a slot); :meth:`_finish` pages
+        what is left."""
         if self._codec is None:
             return
         bt = self.kv_spec.block_tokens
         hot = self.kv_spec.hot_blocks
         evict = (self._evict_slot_async if self.kv_paging == "async"
                  else self._evict_slot)
-        while seq.evicted + (1 + hot) * bt <= seq.absorbed:
+        paged = 0
+        while seq.evicted + (1 + hot) * bt <= seq.absorbed and paged != most:
             t0 = seq.evicted
             evict(seq, t0, t0 + bt)
             seq.evicted = t0 + bt
+            paged += 1
 
     def _record_boundary_states(self, seq: _Seq, row, t: int):
         """Snapshot every recurrent layer's state at boundary ``t``
@@ -750,12 +772,13 @@ class Engine:
     # ---- completion / rejection -----------------------------------------
 
     def _finish(self, seq: _Seq):
-        if self._pending:
-            try:
+        try:
+            self._page(seq)
+            if self._pending:
                 self._flush_pending(seq)
-            except PoolExhausted as e:
-                self._reject(seq, e)
-                return
+        except PoolExhausted as e:
+            self._reject(seq, e)
+            return
         seq.state = "finished"
         if seq.slot is not None:
             self._slots[seq.slot] = None
@@ -803,6 +826,7 @@ class Engine:
             "requests": {st: by_state.get(st, 0) for st in
                          ("waiting", "running", "finished", "rejected")},
             "prefill_tokens": self._prefill_tokens,
+            "prefill_bulk_tokens": self._prefill_bulk_tokens,
             "decode_tokens": self._decode_tokens,
             "ms_per_token_prefill": (1e3 * self._prefill_s
                                      / max(1, self._prefill_tokens)),

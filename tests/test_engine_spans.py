@@ -176,3 +176,12 @@ def test_tokens_are_the_same_with_the_profiler_off(model, traced, kind):
     _, toks = _serve(model, kind == "paged")
     assert toks == traced["toks"][kind]
     assert all(len(t) == NEW_TOKENS for t in toks.values())
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_prefill_spans_carry_their_mode(traced, kind):
+    spans = traced[f"{kind}_spans"]
+    assert [sp[4]["mode"] for sp in spans if sp[0] == "engine.prefill"] == [
+        "bulk"] * len(PROMPTS)
+    st = traced[kind].stats()
+    assert st["prefill_bulk_tokens"] == st["prefill_tokens"] == sum(PROMPTS)

@@ -187,6 +187,29 @@ class TestEngineOracle:
         assert st["pool"]["unique_blocks"] > 0
         assert st["pool"]["logical_bytes"] == 0    # all refs released
 
+    @pytest.mark.parametrize("new,per_step", [(8, [1, 1, 1, 1, 1, 0, 1]),
+                                              (2, [4])])
+    def test_sync_paging_codes_one_block_a_step(self, phi3, new, per_step):
+        """A 17-token prompt's four blocks page out one a step after its
+        admission, not all in the admission step; what is left when the
+        request finishes pages then. Every completed block is pooled
+        and the tokens stay exact."""
+        cfg, params = phi3
+        prompt = _prompts(cfg, [17], seed=7)[0]
+        eng = Engine(params, cfg, max_seq_len=32, max_batch=1,
+                     kv_spec=KVCacheSpec(block_tokens=4),
+                     registry=CodecRegistry())
+        h = eng.submit(GenerationRequest(prompt=prompt, max_new_tokens=new))
+        paged = [0]
+        while True:
+            left = eng.step()
+            paged.append(eng.stats()["kv"]["blocks_paged"])
+            if not left:
+                break
+        assert np.diff(paged).tolist() == per_step
+        assert paged[-1] == (17 + new - 1) // 4
+        assert list(eng.poll(h).tokens) == _oracle(params, cfg, prompt, new)
+
     def test_deprecated_generate_matches_scan_oracle(self, setup):
         """The legacy batch call is now an Engine wrapper; it must stay
         bit-identical to the scan implementation it replaced."""
