@@ -3,14 +3,20 @@
 Everything a cell needs is found by name: its entry in
 ``BENCHMARK.json``, ``bench/cells/<cell>.json`` (engine settings),
 ``bench/configs/<config>.json`` (sizes, source, the limit of the
-correctness check) and ``bench/traffic/<mix>.json``; each per-layer
-metric is read by ``bench/metrics/<metric>.py``. A cell is added by
-adding files and entries, never by editing this module.
+correctness check), ``bench/traffic/<mix>.json``, and the model family
+the configuration file names, ``bench/families/<family>.py``: the
+program's configuration and weights, the reference's layers and the
+roofline counts of that architecture (``bench/families/__init__.py``
+lists what a family provides). Each per-layer metric is read by
+``bench/metrics/<metric>.py``. A cell, a configuration or a family is
+added by adding files and entries, never by editing this module, which
+names no model.
 
 The run (``run_cell``):
 
-1. makes the weights on the device from the seed (``bench/weights.py``)
-   and builds ``repro.serving.Engine`` with the cell's settings;
+1. makes the weights on the device from the seed (the family's
+   ``program_params``) and builds ``repro.serving.Engine`` with the
+   cell's settings;
 2. set-up: admits one request per client (one of each prompt bucket,
    so every prefill length compiles; in a paged cell their prompt
    blocks page out, which calibrates the KV codec and compiles its
@@ -21,9 +27,9 @@ The run (``run_cell``):
    returns;
 4. reads the peak device memory, frees the program's state, and runs
    the reference over a seeded sample of the finished requests
-   (``bench/reference.py``): ``correct`` holds when no served token's
-   reference logit lies further below the reference's best than the
-   configuration's limit.
+   (``bench/reference.py`` with the family's layers): ``correct`` holds
+   when no served token's reference logit lies further below the
+   reference's best than the configuration's limit.
 """
 from __future__ import annotations
 
@@ -37,11 +43,12 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import roofline, traffic, window
+from bench import families, roofline, traffic, window
 from bench.window import Record
 
 SPANS = ("admit", "prefill", "decode", "page", "step")
@@ -61,6 +68,7 @@ class Cell:
     name: str
     entry: dict
     config: dict
+    family: ModuleType       # bench/families/<family>.py
     mix: dict
     settings: dict
     end_to_end: List[dict]
@@ -81,10 +89,15 @@ def load_cell(root, name: str) -> Cell:
         raise KeyError(f"no workload {name!r}; have {sorted(entries)}")
     entry = entries[name]
     home = root / "bench"
-    config = json.loads((home / "configs" / f"{entry['config']}.json").read_text())
+    config_file = home / "configs" / f"{entry['config']}.json"
+    config = json.loads(config_file.read_text())
+    if "family" not in config:
+        raise ValueError(f"{config_file} names no \"family\", the module "
+                         f"{home / 'families'}/<family>.py of its model")
+    family = families.module(config["family"], home / "families")
     mix = traffic.load(home / "traffic" / f"{entry['traffic']}.json")
     settings = json.loads((home / "cells" / f"{name}.json").read_text())
-    return Cell(name, entry, config, mix, settings,
+    return Cell(name, entry, config, family, mix, settings,
                 [m for m in bench["end_to_end"] if _applies(m, name)],
                 [m for m in bench["per_layer"] if _applies(m, name)], root)
 
@@ -102,40 +115,15 @@ def metric_reader(root, name: str):
 # The program under test
 # --------------------------------------------------------------------------
 
-# configuration-file key -> the program's ModelConfig attribute
-_SIZES = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-          "num_hidden_layers": "num_layers", "num_attention_heads": "num_heads",
-          "num_key_value_heads": "num_kv_heads", "head_dim": "resolved_head_dim",
-          "vocab_size": "vocab_size", "rms_norm_eps": "norm_eps",
-          "rope_theta": "rope_theta", "partial_rotary_factor": "rope_fraction"}
-
-
-def program_config(c: dict):
-    """The program's configuration for a benchmark configuration file,
-    checked against every size the file states."""
-    from repro.launch.serve import serving_config
-    prog = c["program"]
-    cfg = serving_config(prog["arch"], reduced=prog.get("reduced", False))
-    bad = [f"{k}: file {c[k]} != program {getattr(cfg, a)}"
-           for k, a in _SIZES.items() if c[k] != getattr(cfg, a)]
-    if (cfg.family, cfg.activation, cfg.param_dtype, cfg.tie_embeddings,
-            cfg.sliding_window, cfg.layer_kinds()) != (
-            "dense", "swiglu", c["torch_dtype"], False, None, ("attention",)):
-        bad.append("not a dense bf16 SwiGLU decoder with an untied head")
-    if bad:
-        raise ValueError(f"{c['name']}: the program serves another model: {bad}")
-    return cfg
-
-
-def build_params(c: dict, cfg, seed: int):
-    """The benchmark's weights in the program's layout, checked leaf by
-    leaf against the program's own parameter tree."""
+def build_params(family, c: dict, cfg, seed: int):
+    """The benchmark's weights in the program's layout (the family's
+    ``program_params``), checked leaf by leaf against the program's own
+    parameter tree."""
     import jax
 
-    from bench import weights
     from repro.models import init_params
     want = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
-    params = weights.program_params(c, seed)
+    params = family.program_params(c, seed)
     got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
     exp = jax.tree.map(lambda a: (a.shape, str(a.dtype)), want)
     if got != exp:
@@ -297,11 +285,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_process: floa
     watch = compile_watch()
     watch.count, watch.names = 0, []
     c, s = cell.config, cell.settings
-    cfg = program_config(c)
+    cfg = cell.family.program_config(c)
     mesh = make_device_mesh()
     counts: Dict[str, int] = {}
     with shd.use_mesh(mesh):
-        params = build_params(c, cfg, seed)
+        params = build_params(cell.family, c, cfg, seed)
         eng = build_engine(cell, params, cfg, mesh)
         stream = traffic.stream(cell.mix, seed, c["vocab_size"])
         loop = Clients(eng, stream, cell.mix["clients"])
@@ -380,7 +368,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_process: floa
     if sample:
         from bench.reference import Reference
         t0 = time.perf_counter()
-        ref = Reference(c, seed, SAMPLE_ROWS, s["max_seq_len"])
+        ref = Reference(cell.family, c, seed, SAMPLE_ROWS, s["max_seq_len"])
         got = ref.gaps([(r.prompt, r.tokens) for r in sample], control=control)
         log(f"reference: {len(sample)} requests, {got['tokens']} served tokens, "
             f"{time.perf_counter() - t0:.3f} s")

@@ -1,12 +1,13 @@
-"""Plain reference of a dense decoder, and its lower-precision control.
+"""Plain reference of a served model, and its lower-precision control.
 
 Written from the published description of the configurations in
-``bench/configs`` (RMSNorm, rotary attention with grouped KV heads,
-SwiGLU MLP, untied head), in float32 at the highest matmul precision.
-It imports nothing of the serving program and takes nothing it made:
-each layer's weights are drawn again from the seed
-(:func:`bench.weights.layer_weights`), one layer at a time, so the
-whole model is never resident in float32.
+``bench/configs``, in float32 at the highest matmul precision: the
+embedding lookup, final RMSNorm and untied head here, each layer's
+weights and forward from the configuration's family
+(``bench/families/<family>.py``). It imports nothing of the serving
+program and takes nothing it made: each layer's weights are drawn
+again from the seed, one layer at a time, so the whole model is never
+resident in float32.
 
 :meth:`Reference.gaps` runs every sampled sequence (prompt + served
 tokens) once and returns, for each served token, how far its reference
@@ -24,21 +25,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench.weights import base_key
 
 HI = jax.lax.Precision.HIGHEST
 
 
-def _mm(x, w):
+def mm(x, w):
     return jnp.einsum("...i,io->...o", x, w.astype(jnp.float32), precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, c):
+def rope(x, pos, c):
     """x [N, S, n, hd] in the source's rotary layout; pos [N, S]."""
     hd = x.shape[-1]
     rot = int(hd * c["partial_rotary_factor"]) // 2 * 2
@@ -53,26 +54,6 @@ def _rope(x, pos, c):
         rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                             -1).reshape(x[..., :rot].shape)
     return jnp.concatenate([rotated, x[..., rot:]], -1)
-
-
-def layer_forward(c, w, x):
-    """One decoder layer over full causal sequences. x: [N, S, d] f32."""
-    n, s, _ = x.shape
-    h, kv, hd = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (n, s))
-    a = _rms(x, w["input_norm"], c["rms_norm_eps"])
-    q = _rope(_mm(a, w["q"]).reshape(n, s, h, hd), pos, c)
-    k = _rope(_mm(a, w["k"]).reshape(n, s, kv, hd), pos, c)
-    v = _mm(a, w["v"]).reshape(n, s, kv, hd)
-    k = jnp.repeat(k, h // kv, axis=2)          # query head i reads kv i // (h/kv)
-    v = jnp.repeat(v, h // kv, axis=2)
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q, k, precision=HI) * hd ** -0.5
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    att = jnp.einsum("nhqk,nkhd->nqhd", probs, v, precision=HI)
-    x = x + _mm(att.reshape(n, s, h * hd), w["o"])
-    b = _rms(x, w["post_norm"], c["rms_norm_eps"])
-    return x + _mm(jax.nn.silu(_mm(b, w["gate"])) * _mm(b, w["up"]), w["down"])
 
 
 def e4m3_block32(w):
@@ -93,15 +74,21 @@ def _quantized(w):
 class Reference:
     """Reference forward at a fixed shape: ``rows`` sequences of
     ``seq_len`` positions (shorter ones padded at the end, where
-    causality keeps the pad from reaching real positions)."""
+    causality keeps the pad from reaching real positions). ``family``
+    is the configuration's family module; each kind of layer it names
+    compiles once, the layer's index traced."""
 
-    def __init__(self, c: dict, seed: int, rows: int, seq_len: int):
-        self.c, self.rows, self.seq_len = c, rows, seq_len
-        self.key = W.base_key(seed)
-        self._layer_w = jax.jit(functools.partial(W.layer_weights, c))
-        self._outer_w = jax.jit(functools.partial(W.outer_weights, c))
-        self._layer = jax.jit(functools.partial(layer_forward, c))
-        self._layer_q = jax.jit(lambda w, x: layer_forward(c, _quantized(w), x))
+    def __init__(self, family, c: dict, seed: int, rows: int, seq_len: int):
+        self.family, self.c, self.rows, self.seq_len = family, c, rows, seq_len
+        self.key = base_key(seed)
+        self._layer_w = jax.jit(functools.partial(family.layer_weights, c),
+                                static_argnums=2)
+        self._outer_w = jax.jit(functools.partial(family.outer_weights, c))
+        self._layer = jax.jit(functools.partial(family.layer_forward, c),
+                              static_argnums=0)
+        self._layer_q = jax.jit(
+            lambda kind, w, x: family.layer_forward(c, kind, _quantized(w), x),
+            static_argnums=0)
         self._embed = jax.jit(self._embed_fn, static_argnums=2)
         self._head = jax.jit(self._head_fn, static_argnums=4)
 
@@ -112,12 +99,12 @@ class Reference:
     def _head_fn(self, o, x, xc, targets, control):
         eps = self.c["rms_norm_eps"]
         head = o["lm_head"]
-        logits = _mm(_rms(x, o["final_norm"], eps), head)
+        logits = mm(rms(x, o["final_norm"], eps), head)
         best = jnp.max(logits, -1)
         gap = best - jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
         if not control:
             return gap, jnp.zeros_like(gap)
-        lc = _mm(_rms(xc, o["final_norm"], eps), e4m3_block32(head))
+        lc = mm(rms(xc, o["final_norm"], eps), e4m3_block32(head))
         first = jnp.argmax(lc, -1)
         gap_c = best - jnp.take_along_axis(logits, first[..., None], -1)[..., 0]
         return gap, gap_c
@@ -144,10 +131,11 @@ class Reference:
         x = self._embed(o, jnp.asarray(tokens), False)
         xc = self._embed(o, jnp.asarray(tokens), True) if control else x
         for layer in range(self.c["num_hidden_layers"]):
-            w = self._layer_w(self.key, layer)
-            x = self._layer(w, x)
+            kind = self.family.layer_kind(self.c, layer)
+            w = self._layer_w(self.key, layer, kind)
+            x = self._layer(kind, w, x)
             if control:
-                xc = self._layer_q(w, xc)
+                xc = self._layer_q(kind, w, xc)
         gap, gap_c = self._head(o, x, xc, jnp.asarray(targets), control)
         gap, gap_c = np.asarray(gap)[mask], np.asarray(gap_c)[mask]
         out = {"logit_gap_max": float(gap.max()), "tokens": int(mask.sum())}

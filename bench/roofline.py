@@ -4,14 +4,22 @@ Counts are what the algorithm needs, not what a program happens to
 compute: attention covers the positions a token attends to, and a
 paged block's bytes are the least any codec must move (the dense block
 read once and written once). ``c`` is a configuration file of
-``bench/configs``.
+``bench/configs``; the counts that depend on the architecture
+(:func:`matmul_params`, :func:`token_flops`, :func:`prefill_flops`,
+:func:`kv_bytes_per_token`) are its family's
+(``bench/families/<family>.py``), and this module serves them to the
+metrics under names that stay put.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-BF16 = 2
+from bench import families
+
+
+def _family(c: dict):
+    return families.module(c["family"])
 
 
 def peaks(device_kind: str) -> dict:
@@ -24,36 +32,29 @@ def peaks(device_kind: str) -> dict:
 
 
 def matmul_params(c: dict) -> int:
-    """Weights a token multiplies through: every layer's projections and
-    MLP, and the LM head (the embedding is a lookup)."""
-    d, h, kv, hd, f = (c["hidden_size"], c["num_attention_heads"],
-                       c["num_key_value_heads"], c["head_dim"],
-                       c["intermediate_size"])
-    layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
-    return c["num_hidden_layers"] * layer + d * c["vocab_size"]
+    """Weights a token multiplies through (the embedding is a lookup)."""
+    return _family(c).matmul_params(c)
 
 
 def token_flops(c: dict, ctx: int) -> int:
     """FLOPs of one token that attends to ``ctx`` positions (itself
-    included): two per multiply-add of the matmuls, plus the scores and
-    the weighted sum of values in every layer."""
-    attn = 4 * c["num_hidden_layers"] * c["num_attention_heads"] * c["head_dim"] * ctx
-    return 2 * matmul_params(c) + attn
+    included)."""
+    return _family(c).token_flops(c, ctx)
 
 
 def prefill_flops(c: dict, prompt_len: int) -> int:
     """FLOPs of a prompt: token ``p`` (0-based) attends to ``p + 1``."""
-    n = prompt_len
-    attn = 4 * c["num_hidden_layers"] * c["num_attention_heads"] * c["head_dim"]
-    return 2 * matmul_params(c) * n + attn * n * (n + 1) // 2
+    return _family(c).prefill_flops(c, prompt_len)
 
 
 def kv_bytes_per_token(c: dict) -> int:
-    return c["num_hidden_layers"] * 2 * c["num_key_value_heads"] * c["head_dim"] * BF16
+    """Dense bytes of the KV cache's entries of one token, every layer."""
+    return _family(c).kv_bytes_per_token(c)
 
 
 def kv_block_bytes(c: dict, block_tokens: int) -> int:
-    """Dense bytes of one paged block: keys and values of every layer."""
+    """Dense bytes of one paged block: its tokens' cache entries, every
+    layer."""
     return block_tokens * kv_bytes_per_token(c)
 
 

@@ -73,8 +73,9 @@ def test_the_command_refuses_a_checkout_without_the_program(tmp_path):
 
 
 def test_a_config_the_program_does_not_serve_is_refused():
+    from bench import families
     from bench.tests.util import tiny_config
     c = tiny_config("phi3-mini-3.8b")
     c["intermediate_size"] += 1
     with pytest.raises(ValueError, match="intermediate_size"):
-        harness.program_config(c)
+        families.module(c["family"]).program_config(c)
